@@ -26,6 +26,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr
 
 from ..configs import ARCH_NAMES, get_smoke_config
 from ..kernels.decode_attention import PALLAS_PAGED_KERNELS
@@ -59,15 +60,15 @@ class EntryPoint:
     model: str
     kind: str
     variant: str
-    _make: Callable[[], jax.core.ClosedJaxpr]
-    _jaxpr: jax.core.ClosedJaxpr | None = None
+    _make: Callable[[], ClosedJaxpr]
+    _jaxpr: ClosedJaxpr | None = None
     tokens: int = 1
     kv_pool_bytes: int | None = None
     kv_pool_bytes_fp32: int | None = None
     _memory: object = None  # MemoryStats cache (see analysis.memory)
 
     @property
-    def jaxpr(self) -> jax.core.ClosedJaxpr:
+    def jaxpr(self) -> ClosedJaxpr:
         if self._jaxpr is None:
             self._jaxpr = self._make()
         return self._jaxpr

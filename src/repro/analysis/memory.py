@@ -53,7 +53,7 @@ import math
 import re
 from typing import Iterable
 
-from jax.core import Literal
+from jax.extend.core import Literal
 
 from .rules import Finding
 from .walker import subjaxprs
@@ -159,7 +159,7 @@ def pallas_dma_bytes(eqn) -> int:
         for d in bm.block_shape:
             if isinstance(d, int):
                 block *= d
-        per_cell += block * bm.array_shape_dtype.dtype.itemsize
+        per_cell += block * bm.array_aval.dtype.itemsize
     n_prefetch = gm.num_index_operands
     prefetch = sum(
         aval_bytes(v.aval)
@@ -328,6 +328,7 @@ def entry_memory(entry) -> MemoryStats:
     cached = getattr(entry, "_memory", None)
     if cached is not None:
         return cached
+    from ..roofline import hw
     from ..roofline.analysis import static_memory_seconds
 
     jaxpr = entry.jaxpr
@@ -343,7 +344,9 @@ def entry_memory(entry) -> MemoryStats:
         bytes_per_token=-(-moved // tokens),
         peak_live_bytes=peak_live_bytes(jaxpr),
         kv_pool_bytes=getattr(entry, "kv_pool_bytes", None),
-        roofline_memory_s=static_memory_seconds(float(moved)),
+        # The static pass runs before any device exists; its floor is the
+        # serving chip's, one v5e.
+        roofline_memory_s=static_memory_seconds(float(moved), 1, hw.V5E),
     )
     entry._memory = stats
     return stats

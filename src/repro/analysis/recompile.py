@@ -21,7 +21,7 @@ is also wired into the main-lane smoke benchmarks):
   device->host sync budget (``host_sync.per_step_budget``).
 
 Serving imports stay function-local so ``repro.analysis`` never drags
-the engine in at import time (the engine imports the sanitizer).
+the engine in at import time.
 """
 
 from __future__ import annotations

@@ -4,10 +4,11 @@ Each hidden per-step host sync serializes the host scheduler against
 device compute — exactly what blocks the async-engine refactor
 (ROADMAP). This module makes the syncs *visible and countable*:
 
-* :func:`host_readback` is the engine's single sanctioned choke point
-  for device->host reads (the batched argmax readbacks). Under an
-  active :class:`TransferSanitizer` every call is counted against the
-  current replica-step.
+* :func:`host_readback` (defined in :mod:`repro.serving.readback`, so
+  the engine imports nothing from this package) is the engine's single
+  sanctioned choke point for device->host reads (the batched argmax
+  readbacks). Under an active :class:`TransferSanitizer` every call is
+  counted against the current replica-step.
 * :class:`TransferSanitizer` additionally installs
   ``jax.transfer_guard_device_to_host`` (inert on CPU where d2h is a
   zero-copy buffer view, but it turns unsanctioned transfers into hard
@@ -17,15 +18,15 @@ device compute — exactly what blocks the async-engine refactor
   ``jax.device_get``) to count *unsanctioned* syncs; ``strict=True``
   raises :class:`HostSyncError` on the spot.
 
-The engine calls :func:`mark_engine_step` once per
-``PipelineServer.step`` so counts bucket per replica-step and tests
+The engine calls :func:`repro.serving.readback.mark_engine_step` once
+per ``PipelineServer.step`` so counts bucket per replica-step and tests
 can assert "<= K syncs per step" — the measurable precondition for
 the async engine core. With the async engine it additionally calls
-:func:`mark_engine_phase` around the producer ("dispatch") and
-consumer ("commit") halves of the step, so sanctioned syncs bucket by
-*where* in the step they happened: the async contract is zero
-sanctioned syncs inside the dispatch phase — readbacks drain only at
-the commit boundary (``sanctioned_by_phase``).
+``mark_engine_phase`` around the producer ("dispatch") and consumer
+("commit") halves of the step, so sanctioned syncs bucket by *where*
+in the step they happened: the async contract is zero sanctioned syncs
+inside the dispatch phase — readbacks drain only at the commit
+boundary (``sanctioned_by_phase``).
 
 Caveat: on the CPU backend a raw ``np.asarray(device_array)`` goes
 through the C-level buffer protocol, which neither the transfer guard
@@ -42,15 +43,15 @@ from __future__ import annotations
 import contextlib
 
 import jax
-import numpy as np
+
+from ..serving import readback
+from ..serving.readback import host_readback
 
 __all__ = [
     "HostSyncError",
     "TransferSanitizer",
     "active_sanitizer",
     "host_readback",
-    "mark_engine_phase",
-    "mark_engine_step",
 ]
 
 
@@ -58,42 +59,8 @@ class HostSyncError(RuntimeError):
     """An unsanctioned device->host sync under a strict sanitizer."""
 
 
-_ACTIVE: "TransferSanitizer | None" = None
-_IN_SANCTIONED = False
-
-
 def active_sanitizer() -> "TransferSanitizer | None":
-    return _ACTIVE
-
-
-def host_readback(x) -> np.ndarray:
-    """THE sanctioned device->host readback. Engine code must route
-    every device read through here; anything else is a lint finding."""
-    global _IN_SANCTIONED
-    s = _ACTIVE
-    if s is None:
-        return np.asarray(x)
-    s._step_sanctioned += 1
-    s.sanctioned_by_phase[s.phase] = s.sanctioned_by_phase.get(s.phase, 0) + 1
-    _IN_SANCTIONED = True
-    try:
-        with jax.transfer_guard_device_to_host("allow"):
-            return np.asarray(x)
-    finally:
-        _IN_SANCTIONED = False
-
-
-def mark_engine_step() -> None:
-    """Close the current replica-step's sync bucket (engine hook)."""
-    if _ACTIVE is not None:
-        _ACTIVE.mark_step()
-
-
-def mark_engine_phase(phase: str) -> None:
-    """Tag subsequent syncs with the engine step phase ("dispatch" /
-    "commit" / "other") — engine hook, no-op without a sanitizer."""
-    if _ACTIVE is not None:
-        _ACTIVE.phase = phase
+    return readback.observer()
 
 
 def _array_impl_type():
@@ -117,14 +84,14 @@ class _CountingValue:
 
 
 def _note_unsanctioned(via: str) -> None:
-    s = _ACTIVE
-    if s is None or _IN_SANCTIONED:
+    s = readback.observer()
+    if s is None or readback.in_readback():
         return
     s._step_unsanctioned += 1
     if s.strict:
         raise HostSyncError(
             f"unsanctioned device->host sync via {via}; route engine "
-            "readbacks through repro.analysis.sanitizer.host_readback"
+            "readbacks through repro.serving.readback.host_readback"
         )
 
 
@@ -159,6 +126,12 @@ class TransferSanitizer:
         self._patched: list[tuple] = []
 
     # -- step accounting -------------------------------------------------
+    def note_sanctioned(self) -> None:
+        """Count one :func:`host_readback` against this step and phase."""
+        self._step_sanctioned += 1
+        by_phase = self.sanctioned_by_phase
+        by_phase[self.phase] = by_phase.get(self.phase, 0) + 1
+
     def mark_step(self) -> None:
         self.per_step.append(self._step_sanctioned + self._step_unsanctioned)
         self.sanctioned_total += self._step_sanctioned
@@ -176,8 +149,7 @@ class TransferSanitizer:
 
     # -- install / restore ----------------------------------------------
     def __enter__(self) -> "TransferSanitizer":
-        global _ACTIVE
-        if _ACTIVE is not None:
+        if readback.observer() is not None:
             raise RuntimeError("TransferSanitizer does not nest")
         impl = _array_impl_type()
         orig_value = impl.__dict__["_value"]
@@ -192,12 +164,11 @@ class TransferSanitizer:
         self._patched = [(impl, "_value", orig_value), (impl, "__array__", orig_array)]
         self._stack = contextlib.ExitStack()
         self._stack.enter_context(jax.transfer_guard_device_to_host(self.guard))
-        _ACTIVE = self
+        readback.set_observer(self)
         return self
 
     def __exit__(self, *exc) -> None:
-        global _ACTIVE
-        _ACTIVE = None
+        readback.set_observer(None)
         for impl, name, orig in self._patched:
             setattr(impl, name, orig)
         self._patched = []

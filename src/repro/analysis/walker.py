@@ -15,7 +15,7 @@ from __future__ import annotations
 from collections import Counter
 from typing import Iterator
 
-from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 __all__ = ["subjaxprs", "iter_eqns", "count_primitive", "primitive_counts"]
 

@@ -2,8 +2,9 @@
 
 All three policies return a probability distribution over the devices of
 one group/layer, restricted to the currently *available* devices (active
-and queue-empty). They are written in ``jax.numpy`` so the same code runs
-concretely (router) and traced (inside the jitted network simulator).
+and queue-empty). The same code runs on host arrays with NumPy (the
+serving router, which must not touch the device per admission) and traced
+with ``jax.numpy`` (inside the jitted network simulator).
 
 * ``uniform``   — 1/|available| over available devices.
 * ``long_term`` — Eq. (6): ``r_i = q_lim,i / sum_j q_lim,j`` over available.
@@ -15,6 +16,7 @@ concretely (router) and traced (inside the jitted network simulator).
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = [
     "uniform_probs",
@@ -28,26 +30,34 @@ __all__ = [
 _EPS = 1e-12
 
 
+def _xp(*arrays):
+    """NumPy when every input is a host array, else ``jax.numpy``."""
+    return np if all(isinstance(a, np.ndarray) for a in arrays) else jnp
+
+
 def _masked_normalize(x, mask):
-    x = jnp.where(mask, x, 0.0)
-    total = jnp.sum(x)
-    n_avail = jnp.sum(mask.astype(x.dtype))
+    xp = _xp(x, mask)
+    x = xp.where(mask, x, 0.0)
+    total = xp.sum(x)
+    n_avail = xp.sum(mask.astype(x.dtype))
     # Fall back to uniform-over-available if all mass was zeroed out.
-    fallback = jnp.where(mask, 1.0, 0.0) / jnp.maximum(n_avail, 1.0)
-    return jnp.where(total > _EPS, x / jnp.maximum(total, _EPS), fallback)
+    fallback = xp.where(mask, 1.0, 0.0) / xp.maximum(n_avail, 1.0)
+    return xp.where(total > _EPS, x / xp.maximum(total, _EPS), fallback)
 
 
 def uniform_probs(q_lims, pm, available):
     """Uniform over available devices (q_lims/pm unused, kept for API parity)."""
     del q_lims, pm
-    mask = available.astype(jnp.float32)
-    return mask / jnp.maximum(jnp.sum(mask), 1.0)
+    xp = _xp(available)
+    mask = available.astype(xp.float32)
+    return mask / xp.maximum(xp.sum(mask), 1.0)
 
 
 def long_term_probs(q_lims, pm, available):
     """Eq. (6) restricted to available devices."""
     del pm
-    return _masked_normalize(jnp.asarray(q_lims, dtype=jnp.float32), available)
+    xp = _xp(q_lims, available)
+    return _masked_normalize(xp.asarray(q_lims, dtype=xp.float32), available)
 
 
 def adaptive_probs(q_lims, pm, available, alpha=None):
@@ -57,14 +67,15 @@ def adaptive_probs(q_lims, pm, available, alpha=None):
     devices in PM1 (the lowest-energy mode) get their long-term rate scaled
     by ``z = alpha / N_l`` and the vector is re-normalized.
     """
+    xp = _xp(q_lims, pm, available)
     x = long_term_probs(q_lims, None, available)
-    pm = jnp.asarray(pm)
+    pm = xp.asarray(pm)
     critical = (pm == 1) & available
     n_l = x.shape[-1]
     if alpha is None:
-        alpha = jnp.sum(critical.astype(jnp.float32))
+        alpha = xp.sum(critical.astype(xp.float32))
     z = alpha / n_l
-    x = jnp.where(critical, x * z, x)
+    x = xp.where(critical, x * z, x)
     return _masked_normalize(x, available)
 
 

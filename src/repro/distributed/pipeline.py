@@ -19,7 +19,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["gpipe", "pipeline_apply"]
 
@@ -94,11 +93,11 @@ def pipeline_apply(
         return run(params_local, micro_all)
 
     pspec = jax.tree_util.tree_map(lambda _: P(axis), stacked_params)
-    out = shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stacked_params, micro)
     return out.reshape(B, *out.shape[2:])

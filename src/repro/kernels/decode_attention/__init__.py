@@ -1,6 +1,9 @@
 from .ops import decode_attention, decode_attention_ref
-from .paged import paged_decode_attention
-from .paged_prefill import paged_prefill_attention_pallas
+from .paged import (
+    paged_attention,
+    paged_decode_attention,
+    paged_prefill_attention_pallas,
+)
 from .ref import (
     gather_pages,
     paged_decode_attention_ref,
@@ -21,6 +24,7 @@ __all__ = [
     "PALLAS_PAGED_KERNELS",
     "decode_attention",
     "decode_attention_ref",
+    "paged_attention",
     "paged_decode_attention",
     "paged_decode_attention_ref",
     "paged_prefill_attention",

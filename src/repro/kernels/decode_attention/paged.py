@@ -1,25 +1,40 @@
-"""Pallas TPU paged decode-attention kernel (block-table gather).
+"""Pallas TPU attention over a paged KV cache (block-table walk).
 
 Serving keeps each replica's KV cache as a shared pool of fixed-size
-pages (``serving/cache.py``); a request's context is scattered
-over non-contiguous pages named by its block table. One query token per
-sequence attends to that scattered cache without ever materializing a
-contiguous copy: the grid is (batch, kv_head, block) and the block
-table is a *scalar-prefetch* operand, so each cell's BlockSpec
-``index_map`` resolves the logical block to its physical page and the
-DMA fetches exactly that page — the gather happens in the memory
-system, not in registers. Per-cell partials (m, l, acc) are merged by
-the same tiny XLA log-sum-exp combine as the dense flash-decode kernel
-(:mod:`.decode_attention`).
+pages (``serving/cache.py``); a request's context is scattered over
+non-contiguous pages named by its block table. One kernel,
+:func:`paged_attention`, lets C query tokens per sequence attend to that
+scattered cache without ever materializing a contiguous copy:
 
-Out-of-range logical blocks point at a reserved scratch page; their
-positions are masked by the per-sequence length, so their garbage
-contributes exp(-inf) = 0 to the merge.
+* the grid is (batch, kv-head block, logical block) and the block table
+  is a *scalar-prefetch* operand, so each cell's BlockSpec ``index_map``
+  resolves the logical block to its physical page and the DMA fetches
+  exactly that page — the gather happens in the memory system;
+* the per-lane query offsets are scalar-prefetch too: query ``i`` of
+  lane ``b`` sits at ``offsets[b] + i`` and attends the positions
+  ``<= offsets[b] + i`` (and, with ``window``, ``> offsets[b] + i -
+  window``), masked in-kernel;
+* the softmax runs online across the logical blocks (the last grid
+  axis) in VMEM scratch, so no per-block partials go to HBM.
 
-int8 pools (``kv_dtype="int8"`` serving) carry one fp32 scale per page
-row; passing ``k_scales``/``v_scales`` makes the kernel dequantize each
-fetched page in VMEM, so quantized decode reads a quarter of the fp32
-bytes and never materializes an fp copy of the cache.
+A page block holds every row of the page for ``Hb`` KV heads — the
+pool's last two axes are (KV, D), and the TPU tiles a block's last two
+axes, so ``Hb`` is a multiple of 8 or all the heads. The block is
+converted to fp32 once and each head's rows sliced out of it.
+
+Decode is the C = 1 case (:func:`paged_decode_attention`); chunked
+prefill over a paged prefix is the general case
+(:func:`paged_prefill_attention_pallas`); and dense decode over a
+contiguous per-request cache runs the same kernel with an identity
+block table (:mod:`.ops`).
+
+Logical blocks past a lane's last query (or before its window) skip
+their compute; out-of-range logical blocks point at the pool's reserved
+scratch page. int8 pools (``kv_dtype="int8"`` serving) carry one fp32
+scale per page row; passing ``k_scales``/``v_scales`` makes the kernel
+apply them to the scores and the probabilities in VMEM, so quantized
+attention reads a quarter of the fp32 bytes and never materializes an
+fp copy of the cache.
 """
 
 from __future__ import annotations
@@ -31,60 +46,161 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ref import dot_rows
+
 NEG_INF = -1e30
+
+
+def _heads_per_block(kv_heads: int) -> int:
+    """KV heads per page block: a multiple of the 8-row tile, or all."""
+    return 8 if kv_heads % 8 == 0 else kv_heads
 
 
 def _paged_kernel(
     bt_ref,  # [B, NB] int32 scalar-prefetch: logical block -> physical page
-    len_ref,  # [B] int32 scalar-prefetch: valid entries incl. current token
-    q_ref,  # [1, 1, G, D]
-    k_ref,  # [1, page, 1, D] — the physical page named by bt[b, c]
+    off_ref,  # [B] int32 scalar-prefetch: absolute position of q[:, 0]
+    q_ref,  # [1, Hb, G, C, D] fp32, pre-scaled by 1/sqrt(D)
+    k_ref,  # [1, page, Hb, D] — the physical page named by bt[b, c]
     v_ref,
-    *refs,  # ([ks_ref, vs_ref] when quantized), m_out, l_out, acc_out
+    *refs,  # ([ks_ref, vs_ref] [1, 1, page] when quantized), o_ref, scratch
     page_size: int,
     window: int | None,
-    scale: float,
     quantized: bool,
 ):
     if quantized:
-        ks_ref, vs_ref, m_out, l_out, acc_out = refs
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
-        m_out, l_out, acc_out = refs
+        o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
     ci = pl.program_id(2)
-    cache_len = len_ref[b]
+    _, Hb, G, C, _ = q_ref.shape
+    off = off_ref[b]
+    first = ci * page_size
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # [G, D]
-    k = k_ref[0, :, 0]  # [page, D]
-    v = v_ref[0, :, 0]
-    if quantized:
-        k = k.astype(jnp.float32) * ks_ref[0][:, None]
-        v = v.astype(jnp.float32) * vs_ref[0][:, None]
-    else:
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
+    @pl.when(ci == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [G, page]
-    pos = ci * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-    mask = pos < cache_len
+    live = first <= off + C - 1
     if window is not None:
-        mask = mask & (pos >= cache_len - window)
-    s = jnp.where(mask, s, NEG_INF)
+        live = live & (first + page_size > off - window + 1)
 
-    m = jnp.max(s, axis=1)  # [G]
-    p = jnp.where(mask, jnp.exp(s - m[:, None]), 0.0)
-    l = jnp.sum(p, axis=1)
-    acc = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [G, D]
-    m_out[0, 0, 0] = m
-    l_out[0, 0, 0] = l
-    acc_out[0, 0, 0] = acc
+    @pl.when(live)
+    def _block():
+        kv_pos = first + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
+        q_pos = off + jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+        mask = kv_pos <= q_pos  # [C, page]
+        if window is not None:
+            mask = mask & (kv_pos > q_pos - window)
+        neg = jnp.full(mask.shape, NEG_INF, jnp.float32)
+        zero = jnp.zeros(mask.shape, jnp.float32)
+        k_all = k_ref[0].astype(jnp.float32)  # [page, Hb, D]
+        v_all = v_ref[0].astype(jnp.float32)
+        for h in range(Hb):
+            k = k_all[:, h, :]  # [page, D]
+            v = v_all[:, h, :]
+            for g in range(G):
+                i = h * G + g
+                s = jax.lax.dot_general(
+                    q_ref[0, h, g], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [C, page]
+                if quantized:
+                    s = s * ks_ref[0]
+                s = jnp.where(mask, s, neg)
+                m_prev = m_scr[i]  # [C, 1]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.where(mask, jnp.exp(s - m_new), zero)
+                l_scr[i] = alpha * l_scr[i] + jnp.sum(p, axis=1, keepdims=True)
+                if quantized:
+                    p = p * vs_ref[0]
+                acc_scr[i] = alpha * acc_scr[i] + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [C, D]
+                m_scr[i] = m_new
+
+    @pl.when(ci == pl.num_programs(2) - 1)
+    def _finish():
+        l = jnp.maximum(l_scr[...], jnp.full(l_scr.shape, 1e-30, jnp.float32))
+        o_ref[0] = (acc_scr[...] / l).reshape(o_ref.shape[1:])
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def paged_attention(
+    q: jax.Array,  # [B, C, H, D] (model layout) — C query tokens per lane
+    k_pages: jax.Array,  # [P, page, KV, D] — shared page pool
+    v_pages: jax.Array,
+    block_tables: jax.Array,  # [B, NB] int32 physical page per logical block
+    offsets: jax.Array,  # [B] int32 absolute position of q[:, 0]
+    *,
+    window: int | None = None,
+    k_scales: jax.Array | None = None,  # [P, page] fp32 per-row scales (int8)
+    v_scales: jax.Array | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention of C queries per lane over a paged cache.
+
+    Returns [B, C, H, D]. Rows whose position has no valid entry (a
+    negative offset) come out as zeros; rows past the caller's valid
+    count produce values the caller discards.
+    """
+    B, C_out, H, D = q.shape
+    _, page, KV, _ = k_pages.shape
+    NB = block_tables.shape[1]
+    G = H // KV
+    Hb = _heads_per_block(KV)
+    quantized = k_scales is not None
+
+    # Interpret mode runs the kernel's dots on XLA:CPU (see dot_rows).
+    C = dot_rows(C_out, interpret)
+    q = jnp.pad(q, ((0, 0), (0, C - C_out), (0, 0), (0, 0)))
+    qg = (q.astype(jnp.float32) * D**-0.5).reshape(B, C, KV, G, D)
+    qg = qg.transpose(0, 2, 3, 1, 4)  # [B, KV, G, C, D]
+    kernel = functools.partial(
+        _paged_kernel, page_size=page, window=window, quantized=quantized
+    )
+    page_spec = pl.BlockSpec(
+        (1, page, Hb, D), lambda b, h, c, bt, off: (bt[b, c], 0, h, 0)
+    )
+    head_spec = pl.BlockSpec(
+        (1, Hb, G, C, D), lambda b, h, c, bt, off: (b, h, 0, 0, 0)
+    )
+    in_specs = [head_spec, page_spec, page_spec]
+    operands = [qg, k_pages, v_pages]
+    if quantized:
+        scale_spec = pl.BlockSpec(
+            (1, 1, page), lambda b, h, c, bt, off: (bt[b, c], 0, 0)
+        )
+        in_specs += [scale_spec, scale_spec]
+        operands += [k_scales[:, None, :], v_scales[:, None, :]]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, KV // Hb, NB),
+        in_specs=in_specs,
+        out_specs=head_spec,
+        scratch_shapes=[
+            pltpu.VMEM((Hb * G, C, 1), jnp.float32),
+            pltpu.VMEM((Hb * G, C, 1), jnp.float32),
+            pltpu.VMEM((Hb * G, C, D), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, C, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), offsets.astype(jnp.int32), *operands)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(B, C, H, D)[:, :C_out]
+    return out.astype(q.dtype)
+
+
 def paged_decode_attention(
     q: jax.Array,  # [B, 1, H, D]
     k_pages: jax.Array,  # [P, page, KV, D] — shared page pool
@@ -93,68 +209,34 @@ def paged_decode_attention(
     lengths: jax.Array,  # [B] int32 valid entries incl. current token
     *,
     window: int | None = None,
-    k_scales: jax.Array | None = None,  # [P, page] fp32 per-row scales (int8)
+    k_scales: jax.Array | None = None,
     v_scales: jax.Array | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Single-token attention against a paged KV cache. Returns [B,1,H,D]."""
-    B, _, H, D = q.shape
-    _, page, KV, _ = k_pages.shape
-    NB = block_tables.shape[1]
-    G = H // KV
-    scale = D**-0.5
-    quantized = k_scales is not None
-
-    qg = q.reshape(B, KV, G, D)
-    block_tables = block_tables.astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
-
-    kernel = functools.partial(
-        _paged_kernel, page_size=page, window=window, scale=scale,
-        quantized=quantized,
-    )
-    page_spec = pl.BlockSpec(
-        (1, page, 1, D), lambda b, h, c, bt, ln: (bt[b, c], 0, h, 0)
-    )
-    in_specs = [
-        pl.BlockSpec((1, 1, G, D), lambda b, h, c, bt, ln: (b, h, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
-    operands = [qg, k_pages, v_pages]
-    if quantized:
-        scale_spec = pl.BlockSpec(
-            (1, page), lambda b, h, c, bt, ln: (bt[b, c], 0)
-        )
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scales, v_scales]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, NB),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, c, bt, ln: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, c, bt, ln: (b, h, c, 0)),
-            pl.BlockSpec(
-                (1, 1, 1, G, D), lambda b, h, c, bt, ln: (b, h, c, 0, 0)
-            ),
-        ],
-    )
-    m, l, acc = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, KV, NB, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, NB, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, NB, G, D), jnp.float32),
-        ],
+    return paged_attention(
+        q, k_pages, v_pages, block_tables, jnp.asarray(lengths, jnp.int32) - 1,
+        window=window, k_scales=k_scales, v_scales=v_scales,
         interpret=interpret,
-    )(block_tables, lengths, *operands)
+    )
 
-    # Log-sum-exp merge across logical blocks (tiny XLA reduction).
-    M = jnp.max(m, axis=2, keepdims=True)  # [B,KV,1,G]
-    w = jnp.exp(m - M)  # [B,KV,NB,G]
-    denom = jnp.sum(w * l, axis=2)  # [B,KV,G]
-    numer = jnp.sum(w[..., None] * acc, axis=2)  # [B,KV,G,D]
-    out = numer / jnp.maximum(denom[..., None], 1e-30)
-    return out.reshape(B, 1, H, D).astype(q.dtype)
+
+def paged_prefill_attention_pallas(
+    q: jax.Array,  # [B, C, H, D] (model layout) — C new tokens per lane
+    k_pages: jax.Array,  # [P, page, KV, D] — shared page pool
+    v_pages: jax.Array,
+    block_tables: jax.Array,  # [B, NB] int32 physical page per logical block
+    offsets: jax.Array,  # [B] int32 absolute position of q[:, 0] (>= 0)
+    *,
+    k_scales: jax.Array | None = None,
+    v_scales: jax.Array | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Chunk attention over a paged prefix, gather-free. Returns [B,C,H,D].
+
+    Drop-in for :func:`.ref.paged_prefill_attention` (the XLA gather
+    fallback, which stays as the off-TPU path and test oracle)."""
+    return paged_attention(
+        q, k_pages, v_pages, block_tables, offsets,
+        k_scales=k_scales, v_scales=v_scales, interpret=interpret,
+    )

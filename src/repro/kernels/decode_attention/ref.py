@@ -26,6 +26,18 @@ def quantize_kv(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return q.astype(jnp.int8), scale
 
 
+def dot_rows(c: int, on_cpu: bool) -> int:
+    """Query rows the attention dots run with for ``c`` queries.
+
+    XLA:CPU takes a matrix-vector path for a one-row dot whose sum order
+    differs from the matrix-matrix one, so there a single row is padded
+    to two: a decode row is then bit-identical to the same row of a
+    speculative verify chunk, which the engine's exactness rests on.
+    Other backends run ``c`` rows.
+    """
+    return max(c, 2) if on_cpu else c
+
+
 def gather_pages(
     pages: jnp.ndarray,
     block_tables: jnp.ndarray,
@@ -87,19 +99,21 @@ def paged_prefill_attention(
     whole paged prefix. This fallback materializes each lane's pages
     (one gather, dequantized for int8 pools) and runs masked attention;
     the Pallas kernel that walks the block table directly —
-    :func:`.paged_prefill.paged_prefill_attention_pallas`, the
+    :func:`.paged.paged_prefill_attention_pallas`, the
     multi-query sibling of :func:`.paged.paged_decode_attention` —
     replaces it behind this signature on TPU, and this fallback stays as
     the off-TPU path and test oracle. Query ``i`` of lane ``b`` attends
     positions ``<= offsets[b] + i``; rows past the caller's valid count
     produce garbage that the engine discards. Returns [B, C, H, D].
     """
-    B, C, H, D = q.shape
+    B, C_out, H, D = q.shape
     k = gather_pages(k_pages, block_tables, k_scales)  # [B, S, KV, D]
     v = gather_pages(v_pages, block_tables, v_scales)
     S, KV = k.shape[1], k.shape[2]
     G = H // KV
     scale = D**-0.5
+    C = dot_rows(C_out, jax.default_backend() == "cpu")
+    q = jnp.pad(q, ((0, 0), (0, C - C_out), (0, 0), (0, 0)))
     qg = q.astype(jnp.float32).reshape(B, C, KV, G, D) * scale
     s = jnp.einsum("bckgd,bskd->bckgs", qg, k.astype(jnp.float32))
     q_pos = offsets[:, None] + jnp.arange(C, dtype=jnp.int32)  # [B, C]
@@ -108,7 +122,7 @@ def paged_prefill_attention(
     s = jnp.where(mask[:, :, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bckgs,bskd->bckgd", p, v.astype(jnp.float32))
-    return out.reshape(B, C, H, D).astype(q.dtype)
+    return out.reshape(B, C, H, D)[:, :C_out].astype(q.dtype)
 
 
 def decode_attention_ref(

@@ -2,10 +2,13 @@
 
 TPU-native adaptation of the CUDA selective-scan (DESIGN.md Sec. 7): the
 sequence is processed in chunks along the innermost (sequential) grid
-dimension; within a chunk the recurrence runs as a vectorized associative
-scan over a [chunk, block_d, N] VMEM tile, and the [block_d, N] state is
-carried across chunks in VMEM scratch (no HBM round-trip per step, no
-GPU-style per-thread serial loop).
+dimension, and the state is carried across chunks in VMEM scratch (no
+HBM round-trip per step). The state is kept transposed, ``[N, block_d]``:
+the channel block fills the 128 lanes and the small state dimension the
+sublanes, so a step's ``dt_t``/``x_t`` rows broadcast over sublanes and
+the outer product ``B_t (dt_t x_t)`` and the read-out ``C_t h_t`` are
+matmuls. A chunk runs as a loop over 8-step tiles (8 rows = one sublane
+tile, loaded at aligned offsets), each unrolled.
 
     h_t = exp(dt_t * A) * h_{t-1} + dt_t * B_t * x_t
     y_t = <h_t, C_t>
@@ -20,43 +23,54 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_TILE = 8  # time steps per unrolled tile (one sublane tile)
+
 
 def _scan_kernel(
     x_ref,  # [1, chunk, block_d]
     dt_ref,  # [1, chunk, block_d]
     b_ref,  # [1, chunk, N]
     c_ref,  # [1, chunk, N]
-    a_ref,  # [block_d, N]
-    h0_ref,  # [1, block_d, N]
+    at_ref,  # [N, block_d] — A transposed
+    h0_ref,  # [1, N, block_d]
     y_ref,  # [1, chunk, block_d]
-    hout_ref,  # [1, block_d, N]
-    h_scr,  # VMEM [block_d, N] f32
+    hout_ref,  # [1, N, block_d]
+    h_scr,  # VMEM [N, block_d] f32
 ):
     ci = pl.program_id(2)
     n_chunks = pl.num_programs(2)
+    chunk = x_ref.shape[1]
 
     @pl.when(ci == 0)
     def _init():
         h_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)  # [chunk, block_d]
-    dt = dt_ref[0].astype(jnp.float32)
-    Bm = b_ref[0].astype(jnp.float32)  # [chunk, N]
-    Cm = c_ref[0].astype(jnp.float32)
-    A = a_ref[...].astype(jnp.float32)  # [block_d, N]
+    at = at_ref[...].astype(jnp.float32)  # [N, block_d]
 
-    a = jnp.exp(dt[:, :, None] * A[None])  # [chunk, block_d, N]
-    b = (dt * x)[:, :, None] * Bm[:, None, :]  # [chunk, block_d, N]
+    def tile(j, h):
+        rows = pl.ds(pl.multiple_of(j * _TILE, _TILE), _TILE)
+        x = x_ref[0, rows, :].astype(jnp.float32)  # [8, block_d]
+        dt = dt_ref[0, rows, :].astype(jnp.float32)
+        bm = b_ref[0, rows, :].astype(jnp.float32)  # [8, N]
+        cm = c_ref[0, rows, :].astype(jnp.float32)
+        dx = dt * x
+        ys = []
+        for t in range(_TILE):
+            inject = jax.lax.dot_general(
+                bm[t : t + 1], dx[t : t + 1], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [N, block_d] outer product
+            h = jnp.exp(dt[t : t + 1] * at) * h + inject
+            ys.append(
+                jax.lax.dot_general(
+                    cm[t : t + 1], h, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            )  # [1, block_d]
+        y_ref[0, rows, :] = jnp.concatenate(ys, axis=0).astype(y_ref.dtype)
+        return h
 
-    def combine(l, r):
-        al, bl = l
-        ar, br = r
-        return al * ar, br + ar * bl
-
-    a_cum, b_cum = jax.lax.associative_scan(combine, (a, b), axis=0)
-    h = a_cum * h_scr[...][None] + b_cum  # [chunk, block_d, N]
-    y_ref[0] = jnp.einsum("cdn,cn->cd", h, Cm).astype(y_ref.dtype)
-    h_scr[...] = h[-1]
+    h_scr[...] = jax.lax.fori_loop(0, chunk // _TILE, tile, h_scr[...])
 
     @pl.when(ci == n_chunks - 1)
     def _final():
@@ -84,7 +98,7 @@ def selective_scan_pallas(
     if h0 is None:
         h0 = jnp.zeros((B, Din, N), jnp.float32)
 
-    chunk = min(chunk, S)
+    chunk = -(-min(chunk, S) // _TILE) * _TILE
     block_d = min(block_d, Din)
     s_pad = -S % chunk
     d_pad = -Din % block_d
@@ -107,19 +121,20 @@ def selective_scan_pallas(
             pl.BlockSpec((1, chunk, block_d), lambda b, d, c: (b, c, d)),
             pl.BlockSpec((1, chunk, N), lambda b, d, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, d, c: (b, c, 0)),
-            pl.BlockSpec((block_d, N), lambda b, d, c: (d, 0)),
-            pl.BlockSpec((1, block_d, N), lambda b, d, c: (b, d, 0)),
+            pl.BlockSpec((N, block_d), lambda b, d, c: (0, d)),
+            pl.BlockSpec((1, N, block_d), lambda b, d, c: (b, 0, d)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, block_d), lambda b, d, c: (b, c, d)),
-            pl.BlockSpec((1, block_d, N), lambda b, d, c: (b, d, 0)),
+            pl.BlockSpec((1, N, block_d), lambda b, d, c: (b, 0, d)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Sp, Dp), x.dtype),
-            jax.ShapeDtypeStruct((B, Dp, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, N, Dp), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, block_d), jnp.float32)],
         interpret=interpret,
-    )(x, dt, Bmat, Cmat, A, h0)
+    )(x, dt, Bmat, Cmat, A.T, h0.transpose(0, 2, 1))
+    h_final = h_final.transpose(0, 2, 1)
 
     return y[:, :S, :Din], h_final[:, :Din]

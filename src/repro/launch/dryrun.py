@@ -41,6 +41,7 @@ from ..distributed.sharding import (  # noqa: E402
 from ..models import abstract_params, build_model, count_params  # noqa: E402
 from ..models.inputs import ENC_LEN_DECODE, input_specs  # noqa: E402
 from ..models.transformer import cache_logical_axes  # noqa: E402
+from ..roofline import hw  # noqa: E402
 from ..roofline.analysis import roofline_terms  # noqa: E402
 from ..training import AdamWConfig, make_train_step  # noqa: E402
 from ..training.train_loop import TrainState  # noqa: E402
@@ -229,7 +230,8 @@ def lower_cell(
     if hlo_path:
         with gzip.open(hlo_path, "wt") as f:
             f.write(hlo)
-    terms, hlo_cost = roofline_terms(hlo, chips)
+    # The placeholder host devices stand in for a v5e pod.
+    terms, hlo_cost = roofline_terms(hlo, chips, hw.V5E)
     mf = model_flops(get_config(arch), cell)
 
     result = {

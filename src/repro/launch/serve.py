@@ -5,20 +5,67 @@
 
 Hosts G pipeline groups x R replicas of the (partitioned) model, routes
 requests with the energy-aware scheduler, prints throughput/downtime.
+
+At full width the model keeps its configured dtype (bf16 for the
+registry configs); ``--smoke`` forces float32, where the tests compare
+exact tokens. Parameters are made from ``--seed`` on the device, or,
+with a serving mesh, directly in the shardings of the first replica
+slice, so no chip ever holds a model the mesh spreads.
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
+import os
+from pathlib import Path
 
 import jax
 
 from ..configs import ARCH_NAMES, get_config, get_smoke_config
+from ..distributed.sharding import SERVE_RULES, param_shardings, replica_submeshes
 from ..models import build_model, init_from_template
 from ..models.registry import default_draft_for
 from ..serving import MPPipelineServer, PipelineServer
 from .mesh import make_serving_mesh
+
+_FP32 = {"dtype": "float32", "param_dtype": "float32"}
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at a fixed place.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets nothing. Otherwise the cache goes to ``.jax_cache/`` at the
+    repository root: a fixed path, because the path is part of what a
+    later run must find again. Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def init_params(model, seed: int, mesh=None):
+    """The model's parameters from ``seed``, made where they will live.
+
+    Without a mesh: on the default device. With a serving mesh: inside
+    one jit whose outputs are sharded over the first replica slice with
+    ``SERVE_RULES`` — the placement the engine gives that slice — so the
+    whole model never lands on one chip.
+    """
+    init = functools.partial(
+        init_from_template, model.template, param_dtype=model.cfg.param_dtype
+    )
+    key = jax.random.PRNGKey(seed)
+    if mesh is None:
+        return init(key)
+    first_slice = replica_submeshes(mesh, 1)[0][0]
+    shardings = param_shardings(model.template, first_slice, SERVE_RULES)
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def main() -> None:
@@ -31,6 +78,9 @@ def main() -> None:
         "--policy", choices=["uniform", "long_term", "adaptive"], default="adaptive"
     )
     ap.add_argument("--slots", type=int, default=60)
+    ap.add_argument("--max-len", type=int, default=128,
+                    help="longest context (prompt + generated) a request may "
+                         "reach; sizes each slot's KV reservation")
     ap.add_argument("--max-batch", type=int, default=4,
                     help="continuous-batching slots per (group, replica)")
     ap.add_argument("--max-queue", type=int, default=None,
@@ -94,48 +144,36 @@ def main() -> None:
     ap.add_argument("--harvest", type=float, nargs=2, default=(6.0, 10.0))
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    if args.multiprocess and (args.paged or args.prefill_chunk or args.spec_draft):
+        ap.error("--multiprocess is dense whole-prompt only "
+                 "(no --paged / --prefill-chunk / --spec-draft)")
+    use_compile_cache()
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    cfg = dataclasses.replace(cfg, dtype="float32", param_dtype="float32")
-    model = build_model(cfg)
-    params = init_from_template(model.template, jax.random.PRNGKey(0), cfg.param_dtype)
-
-    spec_draft = None
-    if args.spec_draft is not None:
-        name = (
-            default_draft_for(args.arch) if args.spec_draft == "auto"
-            else args.spec_draft
-        )
-        dcfg = get_smoke_config(name) if args.smoke else get_config(name)
-        dcfg = dataclasses.replace(dcfg, dtype="float32", param_dtype="float32")
-        draft = build_model(dcfg)
-        dparams = init_from_template(
-            draft.template, jax.random.PRNGKey(1), dcfg.param_dtype
-        )
-        spec_draft = (draft, dparams)
+    def config(name):
+        if args.smoke:
+            return dataclasses.replace(get_smoke_config(name), **_FP32)
+        return get_config(name)
 
     common = dict(
         n_groups=args.groups,
         n_replicas=args.replicas,
         policy=args.policy,
         harvest_bounds=tuple(args.harvest),
-        max_len=128,
+        max_len=args.max_len,
         max_batch=args.max_batch,
         max_queue=args.max_queue,
         max_park_steps=args.max_park_steps if args.max_park_steps > 0 else None,
         async_depth=args.async_depth,
         seed=args.seed,
     )
+    spec_draft = None
     if args.multiprocess:
-        if args.paged or args.prefill_chunk or args.spec_draft:
-            ap.error("--multiprocess is dense whole-prompt only "
-                     "(no --paged / --prefill-chunk / --spec-draft)")
         server = MPPipelineServer(
             {
                 "arch": args.arch,
                 "smoke": args.smoke,
-                "overrides": {"dtype": "float32", "param_dtype": "float32"},
-                "seed": 0,
+                "overrides": _FP32 if args.smoke else {},
+                "seed": args.seed,
             },
             mesh_model=args.mesh_model or 1,
             **common,
@@ -146,9 +184,20 @@ def main() -> None:
             mesh = make_serving_mesh(
                 model_axis=args.mesh_model, data_axis=args.mesh_data
             )
+        model = build_model(config(args.arch))
+        if args.spec_draft is not None:
+            name = (
+                default_draft_for(args.arch) if args.spec_draft == "auto"
+                else args.spec_draft
+            )
+            draft = build_model(config(name))
+            spec_draft = (draft, init_params(draft, args.seed + 1, mesh))
+        # The weights go straight in: the server slices its stages from
+        # them, and a reference kept here would hold a second copy of
+        # every layer on the first replica slice.
         server = PipelineServer(
             model,
-            params,
+            init_params(model, args.seed, mesh),
             mesh=mesh,
             paged=args.paged,
             page_size=args.page_size,

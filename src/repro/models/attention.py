@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..distributed.sharding import logical
 from .common import ModelConfig, ParamSpec
@@ -39,11 +40,19 @@ NEG_INF = -1e30
 def attn_template(cfg: ModelConfig, n_layers: int | None = None) -> dict:
     L = n_layers if n_layers is not None else cfg.n_layers
     D, H, KV, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    # Fan-in scales: the projections contract over D (q/k/v) and over
+    # H x Dh (o), not over the second-to-last axis the default assumes.
+    s_in, s_out = D**-0.5, (H * Dh) ** -0.5
+    qkv_axes = ("layers", "embed_fsdp", "heads", "head_dim")
+    kv_axes = ("layers", "embed_fsdp", "kv_heads", "head_dim")
     t = {
-        "wq": ParamSpec((L, D, H, Dh), ("layers", "embed_fsdp", "heads", "head_dim")),
-        "wk": ParamSpec((L, D, KV, Dh), ("layers", "embed_fsdp", "kv_heads", "head_dim")),
-        "wv": ParamSpec((L, D, KV, Dh), ("layers", "embed_fsdp", "kv_heads", "head_dim")),
-        "wo": ParamSpec((L, H, Dh, D), ("layers", "heads", "head_dim", "embed_fsdp")),
+        "wq": ParamSpec((L, D, H, Dh), qkv_axes, scale=s_in),
+        "wk": ParamSpec((L, D, KV, Dh), kv_axes, scale=s_in),
+        "wv": ParamSpec((L, D, KV, Dh), kv_axes, scale=s_in),
+        "wo": ParamSpec(
+            (L, H, Dh, D), ("layers", "heads", "head_dim", "embed_fsdp"),
+            scale=s_out,
+        ),
     }
     if cfg.qkv_bias:
         t["bq"] = ParamSpec((L, H, Dh), ("layers", "heads", "head_dim"), init="zeros")
@@ -296,6 +305,31 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _per_head_shard(kernel, q, k, v, *rest):
+    """Call a Pallas attention kernel under the ambient serving mesh.
+
+    XLA cannot partition a Mosaic kernel. When a ``model`` mesh axis is
+    in scope (the engine enters its replica's abstract mesh around each
+    dispatch), the kernel runs under ``shard_map``: q [B, S, H, D] and
+    k/v ([B, S, KV, D] caches or [P, page, KV, D] pools) split over
+    their head axis, every other operand replicated. Heads that do not
+    divide the axis run whole on every device. Without a mesh this is a
+    plain call.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    width = 1 if mesh.empty else dict(mesh.shape).get("model", 1)
+    if width == 1:
+        return kernel(q, k, v, *rest)
+    split = q.shape[2] % width == 0 and k.shape[2] % width == 0
+    heads = P(None, None, "model" if split else None, None)
+    return jax.shard_map(
+        kernel,
+        in_specs=(heads, heads, heads) + (P(),) * len(rest),
+        out_specs=heads,
+        check_vma=False,
+    )(q, k, v, *rest)
+
+
 def attention_block(
     x: jax.Array,
     p: dict,
@@ -322,9 +356,12 @@ def attention_block(
         if cfg.attn_impl == "pallas":
             from ..kernels.flash_attention import flash_attention
 
-            out = flash_attention(
-                q, k, v, causal=causal, window=window_static,
-                interpret=_use_interpret(),
+            out = _per_head_shard(
+                lambda q, k, v: flash_attention(
+                    q, k, v, causal=causal, window=window_static,
+                    interpret=_use_interpret(),
+                ),
+                q, k, v,
             )
         else:
             out = chunked_attention(
@@ -348,9 +385,11 @@ def attention_block(
     if cfg.attn_impl == "pallas":
         from ..kernels.decode_attention import decode_attention as decode_kernel
 
-        out = decode_kernel(
-            q, k_cache, v_cache, cache_len, window=window_static,
-            interpret=_use_interpret(),
+        out = _per_head_shard(
+            lambda q, k, v, n: decode_kernel(
+                q, k, v, n, window=window_static, interpret=_use_interpret()
+            ),
+            q, k_cache, v_cache, jnp.asarray(cache_len, jnp.int32),
         )
     else:
         out = decode_attention(
@@ -422,15 +461,18 @@ def paged_attention_block(
     if cfg.attn_impl == "pallas":
         from ..kernels.decode_attention import paged_decode_attention
 
-        out = paged_decode_attention(
+        out = _per_head_shard(
+            lambda q, k, v, bt, n, ks, vs: paged_decode_attention(
+                q, k, v, bt, n, k_scales=ks, v_scales=vs,
+                interpret=_use_interpret(),
+            ),
             q, pages["k"], pages["v"], block_tables, attn_len,
-            k_scales=pages.get("k_scale"), v_scales=pages.get("v_scale"),
-            interpret=_use_interpret(),
+            pages.get("k_scale"), pages.get("v_scale"),
         )
-    else:
-        # XLA path: gather the pages (dequantizing int8 rows), then the
-        # dense decode oracle with per-request lengths ([B,1] broadcasts
-        # against the position row).
+    elif cfg.decode_mulsum or cfg.attn_kv_stream:
+        # The dense-decode perf variants over the gathered pages
+        # (dequantized for int8 pools; [B,1] lengths broadcast against
+        # the position row).
         from ..kernels.decode_attention import gather_pages
 
         k_cache = gather_pages(pages["k"], block_tables, pages.get("k_scale"))
@@ -438,6 +480,16 @@ def paged_attention_block(
         out = decode_attention(
             q, k_cache, v_cache, attn_len[:, None],
             mulsum=cfg.decode_mulsum, kv_stream=cfg.attn_kv_stream,
+        )
+    else:
+        # The chunk fallback with one query per lane: decode and a
+        # speculative verify chunk share one reduction, so verify is
+        # exact against sequential decode.
+        from ..kernels.decode_attention import paged_prefill_attention
+
+        out = paged_prefill_attention(
+            q, pages["k"], pages["v"], block_tables, positions[:, 0],
+            k_scales=pages.get("k_scale"), v_scales=pages.get("v_scale"),
         )
     o = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dtype))
     return o, pages
@@ -512,10 +564,13 @@ def paged_chunk_attention_block(
     if cfg.attn_impl == "pallas":
         from ..kernels.decode_attention import paged_prefill_attention_pallas
 
-        out = paged_prefill_attention_pallas(
+        out = _per_head_shard(
+            lambda q, k, v, bt, off, ks, vs: paged_prefill_attention_pallas(
+                q, k, v, bt, off, k_scales=ks, v_scales=vs,
+                interpret=_use_interpret(),
+            ),
             q, pages["k"], pages["v"], block_tables, positions[:, 0],
-            k_scales=pages.get("k_scale"), v_scales=pages.get("v_scale"),
-            interpret=_use_interpret(),
+            pages.get("k_scale"), pages.get("v_scale"),
         )
     else:
         from ..kernels.decode_attention import paged_prefill_attention

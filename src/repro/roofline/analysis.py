@@ -41,7 +41,6 @@ __all__ = [
     "parse_computations",
     "roofline_terms",
     "static_memory_seconds",
-    "static_roofline_terms",
     "top_contributors",
     "trip_count",
 ]
@@ -414,18 +413,19 @@ class RooflineTerms:
     hbm_bytes: float  # total bytes accessed
     collective_bytes: float  # total collective payload bytes
     chips: int
+    device_kind: str  # peaks key (``jax.Device.device_kind``)
 
     @property
     def compute_s(self) -> float:
-        return self.flops / (self.chips * hw.PEAK_FLOPS_BF16)
+        return self.flops / (self.chips * hw.peaks(self.device_kind).flops_bf16)
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / (self.chips * hw.HBM_BW)
+        return self.hbm_bytes / (self.chips * hw.peaks(self.device_kind).hbm_bw)
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / (self.chips * hw.ICI_BW_PER_LINK)
+        return self.collective_bytes / (self.chips * hw.peaks(self.device_kind).ici_link_bw)
 
     @property
     def dominant(self) -> str:
@@ -455,37 +455,32 @@ class RooflineTerms:
         }
 
 
-def roofline_terms(hlo_text: str, chips: int) -> tuple[RooflineTerms, HloCost]:
+def roofline_terms(
+    hlo_text: str, chips: int, device_kind: str
+) -> tuple[RooflineTerms, HloCost]:
     """Trip-scaled terms from the post-SPMD HLO (per-device program);
-    totals scale by ``chips``, the per-chip time terms divide them out."""
+    totals scale by ``chips``, the per-chip time terms divide them out
+    against the peaks of ``device_kind``."""
     cost = analyze_hlo(hlo_text)
     terms = RooflineTerms(
         flops=cost.flops * chips,
         hbm_bytes=cost.bytes * chips,
         collective_bytes=cost.collective_bytes * chips,
         chips=chips,
+        device_kind=device_kind,
     )
     return terms, cost
 
 
-def static_memory_seconds(required_bytes: float, chips: int = 1) -> float:
+def static_memory_seconds(
+    required_bytes: float, chips: int, device_kind: str
+) -> float:
     """Attainable-bandwidth floor on step time from *statically* required
     bytes — the jaxpr-level memory pass (``repro.analysis.memory``) feeds
     its per-entry transfer bytes through here, so the roofline's memory
     term is available before anything compiles, not just from
     post-optimization HLO."""
-    return required_bytes / (chips * hw.HBM_BW)
-
-
-def static_roofline_terms(required_bytes: float, chips: int = 1) -> RooflineTerms:
-    """A memory-only :class:`RooflineTerms` from static required bytes
-    (FLOPs/collectives unknown before compilation → zero)."""
-    return RooflineTerms(
-        flops=0.0,
-        hbm_bytes=float(required_bytes),
-        collective_bytes=0.0,
-        chips=chips,
-    )
+    return required_bytes / (chips * hw.peaks(device_kind).hbm_bw)
 
 
 def top_contributors(

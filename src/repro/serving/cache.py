@@ -109,8 +109,9 @@ class PagePool:
         return len(self._owner)
 
     def blocks_for(self, length: int) -> int:
-        """Pages needed to hold ``length`` cache entries (min 1)."""
-        return max(1, -(-int(length) // self.page_size))
+        """Pages needed to hold ``length`` cache entries. The one
+        zero-length rule: an empty context holds no page."""
+        return -(-int(length) // self.page_size)
 
     def can_alloc(self, n: int) -> bool:
         return n <= len(self._free)
@@ -363,12 +364,10 @@ class PagedKVCache(KVCacheManager):
 
     # -- lifecycle -------------------------------------------------------
     def reserve(self, rid: int, length: int) -> int:
-        if length > 0 and not self.pool.can_alloc(self.pool.blocks_for(length)):
+        if not self.pool.can_alloc(self.pool.blocks_for(length)):
             raise PageError(f"paged reserve of {length} entries refused")
         slot = self._take_slot(rid)
-        self.pages[rid] = (
-            self.pool.alloc(self.pool.blocks_for(length), rid) if length > 0 else []
-        )
+        self.pages[rid] = self.pool.alloc(self.pool.blocks_for(length), rid)
         self._set_row(slot, self.pages[rid])
         return slot
 
@@ -405,10 +404,9 @@ class PagedKVCache(KVCacheManager):
         if n == 0:
             return
         held = self.pages.get(rid, [])
-        # A zero-length context keeps zero pages (mirrors reserve(0));
-        # otherwise the tail pages the shorter context no longer touches
-        # go back to the pool and the block-table row is re-scratched.
-        need = self.pool.blocks_for(new_len) if new_len > 0 else 0
+        # The tail pages the shorter context no longer touches go back
+        # to the pool and the block-table row is re-scratched.
+        need = self.pool.blocks_for(new_len)
         if len(held) > need:
             tail = held[need:]
             del held[need:]

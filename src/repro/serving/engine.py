@@ -144,6 +144,7 @@ reported per *accepted token* (``ServerStats.accepted_tokens``,
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import Counter, deque
@@ -156,7 +157,7 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..analysis.sanitizer import host_readback, mark_engine_phase, mark_engine_step
+from .readback import host_readback, mark_engine_phase, mark_engine_step
 from ..core.power import PowerModePolicy, dynamic_policy
 from ..distributed.sharding import (
     SERVE_RULES,
@@ -1209,6 +1210,17 @@ class PipelineServer:
             return self.stages[g][1]
         return self._placed_params[(g, self._slice_of[r])]
 
+    def _trace_mesh(self, r: int):
+        """Replica ``r``'s abstract mesh, in scope while its dispatches
+        trace: the Pallas attention kernels read it to ``shard_map``
+        themselves over heads (XLA cannot partition a Mosaic kernel).
+        A no-op without a mesh."""
+        if self._replica_meshes is None:
+            return contextlib.nullcontext()
+        return jax.sharding.use_abstract_mesh(
+            self._replica_meshes[r].abstract_mesh
+        )
+
     def _place(self, r: int, x):
         """Commit an array (or tree) to replica ``r``'s submesh, replicated.
 
@@ -1471,18 +1483,19 @@ class PipelineServer:
 
         readbacks: list[tuple] = []
         ex = self._exec[g]
-        if whole_jobs:
-            ex.run_prefill_whole(r, whole_jobs, outputs, mgr, readbacks)
-        if chunk_jobs:
-            ex.run_chunks(r, chunk_jobs, outputs, mgr, readbacks)
-        if spec_jobs:
-            # Stage 0 drafts first (its readback precedes the verify's in
-            # the call's drain order — the accept finalizer needs the
-            # round's drafts already patched in).
-            tok_dev = self._run_draft(r, spec_jobs, readbacks) if g == 0 else None
-            ex.run_verify(r, spec_jobs, outputs, mgr, readbacks, tok_dev)
-        if decode_jobs:
-            ex.run_decode(r, decode_jobs, outputs, mgr, readbacks)
+        with self._trace_mesh(r):
+            if whole_jobs:
+                ex.run_prefill_whole(r, whole_jobs, outputs, mgr, readbacks)
+            if chunk_jobs:
+                ex.run_chunks(r, chunk_jobs, outputs, mgr, readbacks)
+            if spec_jobs:
+                # Stage 0 drafts first (its readback precedes the verify's
+                # in the call's drain order — the accept finalizer needs
+                # the round's drafts already patched in).
+                tok_dev = self._run_draft(r, spec_jobs, readbacks) if g == 0 else None
+                ex.run_verify(r, spec_jobs, outputs, mgr, readbacks, tok_dev)
+            if decode_jobs:
+                ex.run_decode(r, decode_jobs, outputs, mgr, readbacks)
 
         self.stats.stage_executions += len(served)
         for m in served:

@@ -40,7 +40,10 @@ Design points:
 
 Scope: dense whole-prompt mode only. Paged KV, chunked prefill and
 speculative decoding run in-process (their substrate is shared device
-memory); requesting them here raises a clear ``ValueError``.
+memory); requesting them here raises a clear ``ValueError``. CPU
+backend only: a chip belongs to one process, so on an accelerator the
+constructor refuses (one process drives every chip of a host through
+:class:`~repro.serving.engine.PipelineServer`).
 """
 
 from __future__ import annotations
@@ -61,7 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..analysis.sanitizer import host_readback
+from .readback import host_readback
 from ..configs import get_config, get_smoke_config
 from ..core.network import DeviceSpec
 from ..distributed.sharding import SERVE_RULES, param_shardings
@@ -527,6 +530,13 @@ class MPPipelineServer(PipelineServer):
         n_replicas: int = 2,
         **kw,
     ):
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                "MPPipelineServer is the CPU harness for the worker-kill "
+                "test: each worker process would open an accelerator that "
+                f"this process already holds ({jax.default_backend()}). "
+                "Serve on a chip with PipelineServer (mesh= for several)."
+            )
         for bad in ("paged", "prefill_chunk", "spec_draft", "kv_dtype", "mesh"):
             if kw.get(bad):
                 raise ValueError(

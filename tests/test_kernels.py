@@ -100,6 +100,40 @@ class TestDecodeAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
+class TestPagedAttention:
+    """The one paged kernel at the page sizes and KV dtypes served on the
+    chip: a block of 8 KV heads (KV=16) and a block of all heads (KV=4),
+    decode (C=1) and a prefill chunk, int8 pages with their scales."""
+
+    @pytest.mark.parametrize("page", [16, 32])
+    @pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+    @pytest.mark.parametrize("KV,G,C", [(16, 1, 1), (4, 2, 5)])
+    def test_matches_oracle(self, page, kv_dtype, KV, G, C):
+        from repro.kernels.decode_attention import (
+            paged_attention,
+            paged_prefill_attention,
+            quantize_kv,
+        )
+
+        B, NB, D = 2, 3, 64
+        P = B * NB + 1
+        ks = jax.random.split(jax.random.PRNGKey(page + C), 3)
+        q = rand(ks[0], (B, C, KV * G, D), jnp.float32)
+        kp = rand(ks[1], (P, page, KV, D), jnp.float32)
+        vp = rand(ks[2], (P, page, KV, D), jnp.float32)
+        scales = {}
+        if kv_dtype == "int8":
+            kp, k_s = quantize_kv(kp)
+            vp, v_s = quantize_kv(vp)
+            scales = dict(k_scales=k_s, v_scales=v_s)
+        bt = jnp.asarray(np.random.default_rng(page).permutation(P - 1)[: B * NB])
+        bt = bt.reshape(B, NB).astype(jnp.int32)
+        offsets = jnp.asarray([page - C, NB * page - C], jnp.int32)
+        out = paged_attention(q, kp, vp, bt, offsets, interpret=True, **scales)
+        ref = paged_prefill_attention(q, kp, vp, bt, offsets, **scales)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=3e-5, atol=3e-5)
+
+
 class TestRMSNorm:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("R,D", [(8, 128), (100, 256), (1, 512), (300, 64)])

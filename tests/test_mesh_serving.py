@@ -239,6 +239,21 @@ class TestMeshDifferential:
         got = _streams(model, params, cfg, mesh=mesh, paged=paged)
         assert got == ref
 
+    @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+    def test_pallas_kernels_shard_over_heads(self, paged):
+        """XLA cannot partition a Mosaic kernel: under the mesh the
+        attention kernels shard_map themselves over heads, and the
+        streams still match single-device."""
+        import dataclasses
+
+        from repro.models import build_model
+
+        cfg, _, params = tiny_model()
+        model = build_model(dataclasses.replace(cfg, attn_impl="pallas"))
+        ref = _streams(model, params, cfg, mesh=None, paged=paged)
+        mesh = make_serving_mesh(model_axis=4, data_axis=2)
+        assert _streams(model, params, cfg, mesh=mesh, paged=paged) == ref
+
     def test_failover_on_mesh_token_exact(self):
         cfg, model, params = tiny_model()
         rng = np.random.default_rng(0)

@@ -33,6 +33,16 @@ SPEC = {
 }
 
 
+def test_refuses_an_accelerator_backend(monkeypatch):
+    """One process per chip: on a TPU every worker would open a chip the
+    coordinator already holds, so construction refuses up front."""
+    import repro.serving.mpserve as mp
+
+    monkeypatch.setattr(mp.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="CPU harness"):
+        MPPipelineServer(SPEC, n_groups=1, n_replicas=1)
+
+
 class TestProtocol:
     def test_roundtrip(self):
         buf = io.BytesIO()

@@ -106,7 +106,7 @@ class TestPagePool:
 
     def test_blocks_for(self):
         pool = PagePool(8, 16)
-        assert pool.blocks_for(0) == 1  # min one page
+        assert pool.blocks_for(0) == 0  # an empty context holds no page
         assert pool.blocks_for(16) == 1
         assert pool.blocks_for(17) == 2
         assert pool.scratch == 8

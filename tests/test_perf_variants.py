@@ -64,6 +64,34 @@ def test_kv_stream_matches_baseline():
     np.testing.assert_allclose(np.asarray(lb), np.asarray(ls), rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("knob", ["decode_mulsum", "attn_kv_stream"])
+def test_paged_decode_variant_matches_default(knob):
+    """The paged XLA decode honours the dense-decode perf knobs: it
+    traces a different program and gives the default path's logits."""
+    cfg, model_d, params = build("qwen2.5-14b")
+    _, model_v, _ = build("qwen2.5-14b", **{knob: True})
+    W, NB, PAGE, L0 = 2, 4, 8, 5
+    shape = (cfg.n_layers, W * NB + 1, PAGE, cfg.n_kv_heads, cfg.head_dim)
+    pools = {"k": jnp.zeros(shape, jnp.float32), "v": jnp.zeros(shape, jnp.float32)}
+    bt = jnp.arange(W * NB, dtype=jnp.int32).reshape(W, NB)
+    rng = np.random.default_rng(0)
+    prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, (W, L0)), jnp.int32)
+    logits, pools = model_d.prefill_chunk_paged(
+        params, prompt, pools, jnp.zeros((W,), jnp.int32),
+        jnp.full((W,), L0, jnp.int32), bt,
+    )
+    args = (
+        params, jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None],
+        pools, jnp.full((W,), L0, jnp.int32), bt,
+    )
+    assert str(jax.make_jaxpr(model_d.decode_paged)(*args)) != str(
+        jax.make_jaxpr(model_v.decode_paged)(*args)
+    )
+    ld, _ = model_d.decode_paged(*jax.tree_util.tree_map(jnp.array, args))
+    lv, _ = model_v.decode_paged(*jax.tree_util.tree_map(jnp.array, args))
+    np.testing.assert_allclose(np.asarray(ld), np.asarray(lv), rtol=2e-4, atol=2e-4)
+
+
 @pytest.mark.slow
 def test_ring_index_matches_roll():
     """Hymba ring-buffer decode far past the window, both ring impls."""

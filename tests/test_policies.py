@@ -81,3 +81,16 @@ class TestAdaptive:
         p = adaptive_probs(q, pm, arr([True, True, False]))
         assert float(jnp.sum(p)) == pytest.approx(1.0, abs=1e-6)
         assert float(p[2]) == 0.0
+
+
+@pytest.mark.parametrize("fn", [uniform_probs, long_term_probs, adaptive_probs])
+def test_host_arrays_stay_on_the_host(fn):
+    """The serving router routes every admission: on host arrays the
+    policies compute in NumPy (no device dispatch, no device->host read)
+    and agree with the traced jax.numpy path."""
+    q = np.asarray([0.3, 0.1, 0.6], np.float32)
+    pm = np.asarray([1, 2, 1])
+    avail = np.asarray([True, True, False])
+    host = fn(q, pm, avail)
+    assert isinstance(host, np.ndarray)
+    np.testing.assert_allclose(host, fn(arr(q), arr(pm), arr(avail)), rtol=1e-6)

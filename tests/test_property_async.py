@@ -41,7 +41,7 @@ TRACES = st.fixed_dictionaries(
         "paged": st.booleans(),
         "int8": st.booleans(),  # applied only when paged
         "prefill_chunk": st.sampled_from([None, 4]),
-        "kappa_pm": st.integers(min_value=0, max_value=2),
+        "kappa_pm": st.integers(min_value=1, max_value=3),
         "staggered": st.booleans(),
         "n_requests": st.integers(min_value=2, max_value=5),
         "n_tokens": st.integers(min_value=1, max_value=4),

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis", reason="hypothesis not installed (test extra)")
-from hypothesis import given, settings
+from hypothesis import example, given, settings
 from hypothesis import strategies as st
 
 from repro.serving.cache import DenseSlotCache, PagedKVCache
@@ -57,7 +57,7 @@ def _replay(mgr, ops, paged):
                     # Rollback trims the claim to exactly the shorter
                     # context's page need.
                     new_len = int(mgr.lengths[slot])
-                    need = mgr.pool.blocks_for(new_len) if new_len > 0 else 0
+                    need = mgr.pool.blocks_for(new_len)
                     assert len(mgr.pages.get(rid, [])) == need
             else:
                 mgr.release(rid, live.pop(rid))
@@ -71,8 +71,7 @@ def _replay(mgr, ops, paged):
                 # Pages always cover the committed mirror, and the
                 # block-table row mirrors them with a re-scratched tail
                 # (a freed lane must never alias a live page).
-                if n > 0:
-                    assert len(held) >= mgr.pool.blocks_for(n)
+                assert len(held) >= mgr.pool.blocks_for(n)
                 row = list(mgr.block_table[slot])
                 assert row[: len(held)] == held
                 assert all(p == mgr.pool.scratch for p in row[len(held):])
@@ -100,6 +99,7 @@ def test_dense_rollback_property(ops):
 
 
 @given(_OPS, st.integers(0, 2**31 - 1))
+@example(ops=[("reserve", 0, 0)], seed=2)  # try_extend(0) once took a page
 @settings(**SETTINGS)
 def test_rollback_then_rewrite_is_exact(ops, seed):
     """The engine's actual usage: rollback(n) then re-extend to the same
@@ -120,7 +120,5 @@ def test_rollback_then_rewrite_is_exact(ops, seed):
             mgr.rollback(0, slot, n)
             length -= n
         assert int(mgr.lengths[slot]) == length
-        assert len(mgr.pages.get(0, [])) == (
-            mgr.pool.blocks_for(length) if length > 0 else 0
-        )
+        assert len(mgr.pages.get(0, [])) == mgr.pool.blocks_for(length)
         mgr.check_conservation()

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.roofline import hw
 from repro.roofline.analysis import (
     RooflineTerms,
     analyze_hlo,
@@ -127,7 +128,8 @@ class TestPublicApi:
 
 class TestTerms:
     def test_dominant_selection(self):
-        t = RooflineTerms(flops=1e15, hbm_bytes=1e12, collective_bytes=1e13, chips=256)
+        t = RooflineTerms(flops=1e15, hbm_bytes=1e12, collective_bytes=1e13, chips=256,
+                          device_kind=hw.V5E)
         assert t.compute_s > 0
         assert t.dominant == "collective"
         assert t.step_time_s == t.collective_s
@@ -135,7 +137,24 @@ class TestTerms:
     def test_scaling_invariance(self):
         """Per-chip time terms are independent of the chip count used to
         scale totals (totals = per-device x chips)."""
-        t1 = RooflineTerms(flops=256e12, hbm_bytes=256e9, collective_bytes=0, chips=256)
-        t2 = RooflineTerms(flops=512e12, hbm_bytes=512e9, collective_bytes=0, chips=512)
+        t1 = RooflineTerms(flops=256e12, hbm_bytes=256e9, collective_bytes=0, chips=256,
+                           device_kind=hw.V5E)
+        t2 = RooflineTerms(flops=512e12, hbm_bytes=512e9, collective_bytes=0, chips=512,
+                           device_kind=hw.V5E)
         assert t1.compute_s == pytest.approx(t2.compute_s)
         assert t1.memory_s == pytest.approx(t2.memory_s)
+
+    def test_peaks_keyed_by_device_kind(self):
+        v5e = hw.peaks("TPU v5 lite")
+        assert v5e.flops_bf16 == 197e12 and v5e.hbm_bw == 819e9
+        t = RooflineTerms(flops=197e12, hbm_bytes=0, collective_bytes=0, chips=1,
+                          device_kind=hw.V5E)
+        assert t.compute_s == pytest.approx(1.0)
+        with pytest.raises(KeyError, match="no roofline peaks"):
+            hw.peaks("TPU v9 imaginary")
+        bad = RooflineTerms(flops=1, hbm_bytes=1, collective_bytes=0, chips=1,
+                            device_kind="cpu")
+        with pytest.raises(KeyError):
+            bad.compute_s
+        with pytest.raises(TypeError, match="device_kind"):
+            RooflineTerms(flops=1, hbm_bytes=1, collective_bytes=0, chips=1)

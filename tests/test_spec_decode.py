@@ -426,7 +426,7 @@ class TestRollbackFuzz:
                     # Rollback trims the claim to exactly the shorter
                     # context's page need.
                     length = int(mgr.lengths[slot])
-                    need = mgr.pool.blocks_for(length) if length > 0 else 0
+                    need = mgr.pool.blocks_for(length)
                     assert len(mgr.pages.get(rid, [])) == need
             elif live:
                 rid = int(rng.choice(list(live)))
