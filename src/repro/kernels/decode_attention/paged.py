@@ -196,6 +196,7 @@ def paged_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="paged_attention",  # the kernel's op name in a profiler trace
     )(block_tables.astype(jnp.int32), offsets.astype(jnp.int32), *operands)
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, C, H, D)[:, :C_out]
     return out.astype(q.dtype)

@@ -157,7 +157,7 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec
 
-from .readback import host_readback, mark_engine_phase, mark_engine_step
+from .readback import engine_phase, engine_step, host_readback, span
 from ..core.power import PowerModePolicy, dynamic_policy
 from ..distributed.sharding import (
     SERVE_RULES,
@@ -226,11 +226,11 @@ class _StageCall:
     readbacks: list[tuple]
     pm: int
     slots_left: int
+    cid: int = 0  # call id, unique within the server (span arguments)
     t_dispatch: float = 0.0
-    # Stamped the moment the call's device slots complete (dispatch-
-    # observable time) — NOT when the completion queue finally drains
-    # it; TTFT accounting reads these, so a deep ring cannot inflate it.
-    t_ready: float | None = None
+    # Stamped the slot the call's device slots complete (dispatch-
+    # observable) — NOT when the completion queue finally drains it;
+    # ``Request.ttft_slots`` reads it, so a deep ring cannot inflate it.
     ready_slot: int | None = None
 
 
@@ -262,6 +262,21 @@ class ServerStats:
     inflight_peak: int = 0  # max calls in one replica's in-flight ring
     slots: int = 0
     downtime_replica_slots: int = 0  # whole (replica, slot) pairs down
+    stage_calls: int = 0  # stage calls opened; the next call's id
+    # What a profiler recorded of the engine's spans while one ran (all 0
+    # without one, so ServerStats stays deterministic): steps and stage
+    # calls begun, and host seconds blocked in ``serve.readback`` and
+    # issuing stage programs in ``serve.launch``.
+    traced_steps: int = 0
+    traced_calls: int = 0
+    traced_readback_s: float = 0.0
+    traced_launch_s: float = 0.0
+    # Steps (slots) a request took: from submit to the step its first
+    # token landed in ``generated``, and between consecutive tokens.
+    first_token_steps: int = 0
+    first_tokens: int = 0
+    token_gap_steps: int = 0
+    token_gaps: int = 0
     n_groups: int = 1
     n_replicas: int = 1
 
@@ -547,44 +562,45 @@ class _DenseExec:
         W = s.max_batch
         cache = s._caches[(g, r)]
         last = g == s.G - 1
-        mask = np.zeros((W,), bool)
-        offs = np.zeros((W,), np.int32)
-        valids = np.zeros((W,), np.int32)
-        for _, m, _, pos, valid in jobs:
-            slot = m.slot_ids[g]
-            mask[slot] = True
-            offs[slot] = pos
-            valids[slot] = valid
-        if g == 0:
-            buf = np.zeros((W, 1, C), np.int32)
-            for _, m, seq, pos, valid in jobs:
-                buf[m.slot_ids[g], 0, :valid] = seq[pos : pos + valid]
-            inp = {"tokens": jnp.asarray(buf)}
-        else:
-            slots = np.asarray([m.slot_ids[g] for _, m, _, _, _ in jobs], np.int32)
-            hs = jnp.stack(
-                [
-                    s._place(r, _pad_tail(seq[:, pos : pos + valid], C))
-                    for _, _, seq, pos, valid in jobs
-                ]
-            )  # [N, 1, C, D]
-            inp = {
-                "hidden": jnp.zeros((W, 1, C, s.cfg.d_model), hs.dtype)
-                .at[jnp.asarray(slots)]
-                .set(hs)
-            }
-        out, cache = self.chunk_masked(
-            params_g, inp, cache, jnp.asarray(offs), jnp.asarray(valids),
-            jnp.asarray(mask),
-        )
+        with span("serve.inputs"):
+            mask = np.zeros((W,), bool)
+            offs = np.zeros((W,), np.int32)
+            valids = np.zeros((W,), np.int32)
+            for _, m, _, pos, valid in jobs:
+                slot = m.slot_ids[g]
+                mask[slot] = True
+                offs[slot] = pos
+                valids[slot] = valid
+            if g == 0:
+                buf = np.zeros((W, 1, C), np.int32)
+                for _, m, seq, pos, valid in jobs:
+                    buf[m.slot_ids[g], 0, :valid] = seq[pos : pos + valid]
+                inp = {"tokens": jnp.asarray(buf)}
+            else:
+                slots = np.asarray([m.slot_ids[g] for _, m, _, _, _ in jobs], np.int32)
+                hs = jnp.stack(
+                    [
+                        s._place(r, _pad_tail(seq[:, pos : pos + valid], C))
+                        for _, _, seq, pos, valid in jobs
+                    ]
+                )  # [N, 1, C, D]
+                inp = {
+                    "hidden": jnp.zeros((W, 1, C, s.cfg.d_model), hs.dtype)
+                    .at[jnp.asarray(slots)]
+                    .set(hs)
+                }
+            args = (jnp.asarray(offs), jnp.asarray(valids), jnp.asarray(mask))
+        with span("serve.launch") as launch:
+            out, cache = self.chunk_masked(params_g, inp, cache, *args)
+            argmax = jnp.argmax(out[:, 0], axis=-1) if last else None
+            _emit_chunk_outputs(
+                s, g, jobs, outputs, mgr, argmax,
+                lambda slot, valid: out[slot, :, :valid],  # [1, valid, D]
+                readbacks,
+            )
+        s.stats.traced_launch_s += launch.seconds
         s._caches[(g, r)] = cache
         s.stats.chunk_prefill_calls += 1
-        argmax = jnp.argmax(out[:, 0], axis=-1) if last else None
-        _emit_chunk_outputs(
-            s, g, jobs, outputs, mgr, argmax,
-            lambda slot, valid: out[slot, :, :valid],  # [1, valid, D]
-            readbacks,
-        )
 
     def run_decode(self, r, jobs, outputs, mgr: KVCacheManager, readbacks):
         """jobs: [(out_idx, member)] — one masked dispatch over the full
@@ -594,48 +610,52 @@ class _DenseExec:
         cache = s._caches[(g, r)]
         last = g == s.G - 1
         W = s.max_batch
-        mask = np.zeros((W,), bool)
-        slots = np.asarray([m.slot_ids[g] for _, m in jobs], np.int32)
-        mask[slots] = True
-        if g == 0:
-            buf = np.zeros((W, 1, 1), np.int32)
-            for _, m in jobs:
-                buf[m.slot_ids[g], 0, 0] = m.generated[-1]
-            inp = jnp.asarray(buf)
-        else:
-            # Assemble on device: the handoffs are device arrays and a
-            # host round-trip per member would not amortize. After an
-            # upstream re-prefill the handoff carries the whole
-            # prefix; a caching stage only consumes the newest position.
-            hs = jnp.stack(
-                [
-                    s._place(r, m.hidden if m.hidden.shape[1] == 1 else m.hidden[:, -1:])
-                    for _, m in jobs
-                ]
-            )
-            inp = (
-                jnp.zeros((W, 1, 1, s.cfg.d_model), hs.dtype)
-                .at[jnp.asarray(slots)]
-                .set(hs)
-            )
-        out, cache = self.decode_masked(params_g, inp, cache, jnp.asarray(mask))
+        with span("serve.inputs"):
+            mask = np.zeros((W,), bool)
+            slots = np.asarray([m.slot_ids[g] for _, m in jobs], np.int32)
+            mask[slots] = True
+            if g == 0:
+                buf = np.zeros((W, 1, 1), np.int32)
+                for _, m in jobs:
+                    buf[m.slot_ids[g], 0, 0] = m.generated[-1]
+                inp = jnp.asarray(buf)
+            else:
+                # Assemble on device: the handoffs are device arrays and a
+                # host round-trip per member would not amortize. After an
+                # upstream re-prefill the handoff carries the whole
+                # prefix; a caching stage only consumes the newest position.
+                hs = jnp.stack(
+                    [
+                        s._place(r, m.hidden if m.hidden.shape[1] == 1 else m.hidden[:, -1:])
+                        for _, m in jobs
+                    ]
+                )
+                inp = (
+                    jnp.zeros((W, 1, 1, s.cfg.d_model), hs.dtype)
+                    .at[jnp.asarray(slots)]
+                    .set(hs)
+                )
+            mask = jnp.asarray(mask)
+        with span("serve.launch") as launch:
+            out, cache = self.decode_masked(params_g, inp, cache, mask)
+            if last:
+                # Capture concrete slot ints now: by commit time a member's
+                # slot_ids could be rewritten by a later placement.
+                pairs = [(i, m.slot_ids[g]) for i, m in jobs]
+
+                def fin(toks, pairs=pairs):
+                    for i, slot in pairs:
+                        outputs[i] = ("token", int(toks[slot]), 0)
+
+                readbacks.append((jnp.argmax(out[:, 0, -1], axis=-1), fin))
+            else:
+                for i, m in jobs:
+                    outputs[i] = ("hidden", out[m.slot_ids[g]], 0)
+        s.stats.traced_launch_s += launch.seconds
         s._caches[(g, r)] = cache
         s.stats.decode_calls += 1
         for _, m in jobs:
             mgr.lengths[m.slot_ids[g]] += 1
-        if last:
-            # Capture concrete slot ints now: by commit time a member's
-            # slot_ids could be rewritten by a later placement.
-            pairs = [(i, m.slot_ids[g]) for i, m in jobs]
-
-            def fin(toks, pairs=pairs):
-                for i, slot in pairs:
-                    outputs[i] = ("token", int(toks[slot]), 0)
-
-            readbacks.append((jnp.argmax(out[:, 0, -1], axis=-1), fin))
-        else:
-            for i, m in jobs:
-                outputs[i] = ("hidden", out[m.slot_ids[g]], 0)
 
 
 class _PagedExec:
@@ -826,42 +846,43 @@ class _PagedExec:
         W = s.max_batch
         cache = s._caches[(g, r)]
         last = g == s.G - 1
-        offs = np.full((W,), -1, np.int32)  # -1 = masked lane
-        valids = np.zeros((W,), np.int32)
-        for _, m, _, pos, valid in jobs:
-            slot = m.slot_ids[g]
-            offs[slot] = pos
-            valids[slot] = valid
-        if g == 0:
-            buf = np.zeros((W, C), np.int32)
-            for _, m, seq, pos, valid in jobs:
-                buf[m.slot_ids[g], :valid] = seq[pos : pos + valid]
-            inp = jnp.asarray(buf)
-        else:
-            slots = np.asarray([m.slot_ids[g] for _, m, _, _, _ in jobs], np.int32)
-            hs = jnp.stack(
-                [
-                    s._place(r, _pad_tail(seq[:, pos : pos + valid], C)[0])
-                    for _, _, seq, pos, valid in jobs
-                ]
-            )  # [N, C, D]
-            inp = (
-                jnp.zeros((W, C, s.cfg.d_model), hs.dtype)
-                .at[jnp.asarray(slots)]
-                .set(hs)
+        with span("serve.inputs"):
+            offs = np.full((W,), -1, np.int32)  # -1 = masked lane
+            valids = np.zeros((W,), np.int32)
+            for _, m, _, pos, valid in jobs:
+                slot = m.slot_ids[g]
+                offs[slot] = pos
+                valids[slot] = valid
+            if g == 0:
+                buf = np.zeros((W, C), np.int32)
+                for _, m, seq, pos, valid in jobs:
+                    buf[m.slot_ids[g], :valid] = seq[pos : pos + valid]
+                inp = jnp.asarray(buf)
+            else:
+                slots = np.asarray([m.slot_ids[g] for _, m, _, _, _ in jobs], np.int32)
+                hs = jnp.stack(
+                    [
+                        s._place(r, _pad_tail(seq[:, pos : pos + valid], C)[0])
+                        for _, _, seq, pos, valid in jobs
+                    ]
+                )  # [N, C, D]
+                inp = (
+                    jnp.zeros((W, C, s.cfg.d_model), hs.dtype)
+                    .at[jnp.asarray(slots)]
+                    .set(hs)
+                )
+            args = (jnp.asarray(offs), jnp.asarray(valids), mgr.device_block_table())
+        with span("serve.launch") as launch:
+            out, cache = self.chunk_pages(params_g, inp, cache, *args)
+            argmax = jnp.argmax(out, axis=-1) if last else None
+            _emit_chunk_outputs(
+                s, g, jobs, outputs, mgr, argmax,
+                lambda slot, valid: out[slot, :valid][None],  # [1, valid, D]
+                readbacks,
             )
-        out, cache = self.chunk_pages(
-            params_g, inp, cache,
-            jnp.asarray(offs), jnp.asarray(valids), mgr.device_block_table(),
-        )
+        s.stats.traced_launch_s += launch.seconds
         s._caches[(g, r)] = cache
         s.stats.chunk_prefill_calls += 1
-        argmax = jnp.argmax(out, axis=-1) if last else None
-        _emit_chunk_outputs(
-            s, g, jobs, outputs, mgr, argmax,
-            lambda slot, valid: out[slot, :valid][None],  # [1, valid, D]
-            readbacks,
-        )
 
     def run_decode(self, r, jobs, outputs, mgr: PagedKVCache, readbacks):
         """One natively-batched paged dispatch over the slot width.
@@ -873,54 +894,55 @@ class _PagedExec:
         cache = s._caches[(g, r)]
         last = g == s.G - 1
         W = s.max_batch
-        lens_arr = np.full((W,), -1, np.int32)
-        for _, m in jobs:
-            slot = m.slot_ids[g]
-            lens_arr[slot] = mgr.lengths[slot]
-        if g == 0:
-            buf = np.zeros((W, 1), np.int32)
+        with span("serve.inputs"):
+            lens_arr = np.full((W,), -1, np.int32)
             for _, m in jobs:
-                buf[m.slot_ids[g], 0] = m.generated[-1]
-            inp = jnp.asarray(buf)
-        else:
-            slots = np.asarray([m.slot_ids[g] for _, m in jobs], np.int32)
-            # Hand-offs: [1, D] from an upstream decode, [1, S, D]
-            # after an upstream re-prefill (consume the last position).
-            hs = jnp.stack(
-                [
-                    s._place(r, m.hidden if m.hidden.ndim == 2 else m.hidden[:, -1])
-                    for _, m in jobs
-                ]
-            )  # [N, 1, D]
-            inp = (
-                jnp.zeros((W, 1, s.cfg.d_model), hs.dtype)
-                .at[jnp.asarray(slots)]
-                .set(hs)
-            )
-        out, cache = self.decode_fn(
-            params_g, inp, cache,
-            jnp.asarray(lens_arr), mgr.device_block_table(),
-        )
+                slot = m.slot_ids[g]
+                lens_arr[slot] = mgr.lengths[slot]
+            if g == 0:
+                buf = np.zeros((W, 1), np.int32)
+                for _, m in jobs:
+                    buf[m.slot_ids[g], 0] = m.generated[-1]
+                inp = jnp.asarray(buf)
+            else:
+                slots = np.asarray([m.slot_ids[g] for _, m in jobs], np.int32)
+                # Hand-offs: [1, D] from an upstream decode, [1, S, D]
+                # after an upstream re-prefill (consume the last position).
+                hs = jnp.stack(
+                    [
+                        s._place(r, m.hidden if m.hidden.ndim == 2 else m.hidden[:, -1])
+                        for _, m in jobs
+                    ]
+                )  # [N, 1, D]
+                inp = (
+                    jnp.zeros((W, 1, s.cfg.d_model), hs.dtype)
+                    .at[jnp.asarray(slots)]
+                    .set(hs)
+                )
+            args = (jnp.asarray(lens_arr), mgr.device_block_table())
+        with span("serve.launch") as launch:
+            out, cache = self.decode_fn(params_g, inp, cache, *args)
+            if last:
+                pairs = [(i, m.slot_ids[g]) for i, m in jobs]
+
+                def fin(toks, pairs=pairs):
+                    for i, slot in pairs:
+                        outputs[i] = ("token", int(toks[slot]), 0)
+
+                readbacks.append((jnp.argmax(out[:, 0], axis=-1), fin))
+            else:
+                # Hand-offs stay [1, D] (not dense's [1, 1, D]): the
+                # per-member [None] here costs one eagerly-dispatched
+                # expand_dims per request per stage round, which measured
+                # as a whole-percent tokens/s hit; both consumers branch
+                # on ndim instead.
+                for i, m in jobs:
+                    outputs[i] = ("hidden", out[m.slot_ids[g]], 0)
+        s.stats.traced_launch_s += launch.seconds
         s._caches[(g, r)] = cache
         s.stats.decode_calls += 1
         for _, m in jobs:
             mgr.lengths[m.slot_ids[g]] += 1
-        if last:
-            pairs = [(i, m.slot_ids[g]) for i, m in jobs]
-
-            def fin(toks, pairs=pairs):
-                for i, slot in pairs:
-                    outputs[i] = ("token", int(toks[slot]), 0)
-
-            readbacks.append((jnp.argmax(out[:, 0], axis=-1), fin))
-        else:
-            # Hand-offs stay [1, D] (not dense's [1, 1, D]): the
-            # per-member [None] here costs one eagerly-dispatched
-            # expand_dims per request per stage round, which measured
-            # as a whole-percent tokens/s hit; both consumers branch
-            # on ndim instead.
-            for i, m in jobs:
-                outputs[i] = ("hidden", out[m.slot_ids[g]], 0)
 
     def run_verify(self, r, jobs, outputs, mgr: PagedKVCache, readbacks, tok_dev):
         """jobs: [(out_idx, member, seq, pos, valid)] — ONE fixed-shape
@@ -1467,6 +1489,10 @@ class PipelineServer:
 
         outputs: list[tuple] = [None] * len(served)
         whole_jobs, chunk_jobs, decode_jobs, spec_jobs = [], [], [], []
+        pm = self.budgets[g][r].pm
+        kappa = self.pm_policy.mode(pm).kappa
+        cid = self.stats.stage_calls
+        self.stats.stage_calls += 1
         for i, m in enumerate(served):
             item = plan[m.rid]
             if item[0] == "decode":
@@ -1481,9 +1507,17 @@ class PipelineServer:
             else:
                 whole_jobs.append((i, m, item[1]))
 
+        def call_args():
+            return {
+                "g": g, "r": r, "call": cid, "pm": pm, "kappa": kappa,
+                "chunk_lanes": len(chunk_jobs), "decode_lanes": len(decode_jobs),
+                "chunk_tokens": sum(job[4] for job in chunk_jobs),
+                "rids": " ".join(str(m.rid) for m in served),
+            }
+
         readbacks: list[tuple] = []
         ex = self._exec[g]
-        with self._trace_mesh(r):
+        with span("serve.call", call_args) as call_span, self._trace_mesh(r):
             if whole_jobs:
                 ex.run_prefill_whole(r, whole_jobs, outputs, mgr, readbacks)
             if chunk_jobs:
@@ -1497,11 +1531,10 @@ class PipelineServer:
             if decode_jobs:
                 ex.run_decode(r, decode_jobs, outputs, mgr, readbacks)
 
+        self.stats.traced_calls += call_span.recorded
         self.stats.stage_executions += len(served)
         for m in served:
             m.in_call = True
-        pm = self.budgets[g][r].pm
-        kappa = self.pm_policy.mode(pm).kappa
         self.dispatch_log.append((g, r, t_dispatch))
         call = _StageCall(
             members=served,
@@ -1509,6 +1542,7 @@ class PipelineServer:
             readbacks=readbacks,
             pm=pm,
             slots_left=kappa,
+            cid=cid,
             t_dispatch=t_dispatch,
         )
         if self.async_depth == 0:
@@ -1523,38 +1557,41 @@ class PipelineServer:
     def _finalize(self, call: _StageCall) -> None:
         """Drain the call's deferred readbacks (the only host syncs)."""
         for dev, fin in call.readbacks:
-            fin(host_readback(dev))
+            with span("serve.readback", lambda: {"call": call.cid}) as wait:
+                host = self._read(dev)
+            self.stats.traced_readback_s += wait.seconds
+            fin(host)
         call.readbacks = []
+
+    def _read(self, dev):
+        """The host value of one deferred readback (overridable:
+        mpserve's replies arrive from worker processes)."""
+        return host_readback(dev)
 
     def _commit_call(self, g: int, call: _StageCall) -> None:
         self._finalize(call)
         for m, out in zip(call.members, call.outputs):
-            self._commit(m, out, g, call.t_ready, call.ready_slot)
+            self._commit(m, out, g, call.ready_slot)
 
-    def _emit_token(
-        self,
-        req: Request,
-        token: int,
-        t_ready: float | None = None,
-        ready_slot: int | None = None,
-    ) -> None:
+    def _emit_token(self, req: Request, token: int, ready_slot: int | None = None) -> None:
         req.generated.append(token)
+        now = self.stats.slots
         if req.t_first_token is None:
-            # Dispatch-observable time: the slot the device work finished,
-            # not the (possibly later) slot the completion queue drained.
-            req.t_first_token = t_ready if t_ready is not None else time.perf_counter()
+            # Stamped as the token lands in ``generated``, after its
+            # readback: what a client polling between steps sees.
+            # ``slot_first_token`` keeps the slot the device work finished.
+            req.t_first_token = time.perf_counter()
             req.slot_first_token = ready_slot
+            self.stats.first_token_steps += now - req.submit_slot
+            self.stats.first_tokens += 1
+        else:
+            self.stats.token_gap_steps += now - req.slot_last_token
+            self.stats.token_gaps += 1
+        req.slot_last_token = now
         self.stats.tokens_generated += 1
         self.stats.accepted_tokens += 1
 
-    def _commit(
-        self,
-        req: Request,
-        out: tuple,
-        g: int,
-        t_ready: float | None = None,
-        ready_slot: int | None = None,
-    ) -> None:
+    def _commit(self, req: Request, out: tuple, g: int, ready_slot: int | None = None) -> None:
         """Apply a completed stage call's result to the request."""
         req.in_call = False
         kind, value, advance = out
@@ -1567,7 +1604,7 @@ class PipelineServer:
             return
         if kind == "spec_done":
             req.cache_ready[g] = True
-            self._finish_spec_round(req, value, advance, t_ready, ready_slot)
+            self._finish_spec_round(req, value, advance, ready_slot)
             self._advance(req)
             return
         if req.spec_adv is not None and any(req.spec_adv):
@@ -1587,7 +1624,7 @@ class PipelineServer:
             req.chunk_seq = None
             req.cache_ready[g] = True
             if g == self.G - 1:
-                self._emit_token(req, value, t_ready, ready_slot)
+                self._emit_token(req, value, ready_slot)
             else:
                 parts = req.chunk_outs + [value]
                 req.hidden = (
@@ -1598,12 +1635,12 @@ class PipelineServer:
             return
         req.cache_ready[g] = True
         if kind == "token":
-            self._emit_token(req, value, t_ready, ready_slot)
+            self._emit_token(req, value, ready_slot)
         else:
             req.hidden = value
         self._advance(req)
 
-    def _finish_spec_round(self, req, emit, v, t_ready, ready_slot) -> None:
+    def _finish_spec_round(self, req, emit, v, ready_slot) -> None:
         """Commit a speculative round: accept the emitted prefix, rewind
         every stage's rejected tail, validate the draft mirror's accepted
         rows, update acceptance stats, and stream the tokens."""
@@ -1634,7 +1671,7 @@ class PipelineServer:
                 spec.lens[r0][slot0] = L + min(e, spec.k)
         req.spec_drafts = None
         for t in emit:
-            self._emit_token(req, t, t_ready, ready_slot)
+            self._emit_token(req, t, ready_slot)
 
     def _advance(self, req: Request) -> None:
         req.stage += 1
@@ -1651,8 +1688,22 @@ class PipelineServer:
     # ------------------------------------------------------------------
     def step(self) -> None:
         """Advance one slot (the paper's Algorithm 1 outer loop),
-        producer (dispatch) before consumer (commit)."""
+        producer (dispatch) before consumer (commit). The step and its
+        three phases are the spans ``serve.step`` > ``serve.sched``,
+        ``serve.dispatch``, ``serve.commit`` (:mod:`.readback`); as the
+        step ends, its device->host sync bucket closes (a no-op unless a
+        repro.analysis TransferSanitizer is active)."""
         self.stats.slots += 1
+        with engine_step(self.stats.slots) as step_span:
+            with engine_phase("sched"):
+                self._schedule()
+            with engine_phase("dispatch"):
+                self._dispatch()
+            with engine_phase("commit"):
+                self._drain()
+        self.stats.traced_steps += step_span.recorded
+
+    def _schedule(self) -> None:
         sched = self.scheduler
         # 1) harvest + hysteresis + downtime telemetry (whole replica-slots)
         for g in range(self.G):
@@ -1676,10 +1727,11 @@ class PipelineServer:
         sched.replace_parked()
         sched.admit_pending()
 
+    def _dispatch(self) -> None:
         # 5) producer: fill each energy-ready replica's in-flight ring.
         #    Members already in flight are excluded by select_members
         #    (in_call), so queued calls cover disjoint request sets.
-        mark_engine_phase("dispatch")
+        sched = self.scheduler
         for g in range(self.G):
             for r in range(self.R):
                 ring = self._calls[(g, r)]
@@ -1697,11 +1749,11 @@ class PipelineServer:
                         self.stats.inflight_peak, len(ring)
                     )
 
+    def _drain(self) -> None:
         # 6) consumer: charge CE(PM)/kappa per slot per in-flight call
         #    (device-level, amortized over the batch), stamp readiness at
         #    the slot the device work completes, then drain the
         #    completion queue head-first in dispatch order.
-        mark_engine_phase("commit")
         for (g, r), ring in self._calls.items():
             b = self.budgets[g][r]
             if not b.available:
@@ -1714,16 +1766,10 @@ class PipelineServer:
                 # per-accepted-token figure divides this by accepted_tokens.
                 self.stats.energy_charged += mode.ce / mode.kappa
                 call.slots_left -= 1
-                if call.slots_left <= 0 and call.t_ready is None:
-                    call.t_ready = time.perf_counter()
+                if call.slots_left <= 0 and call.ready_slot is None:
                     call.ready_slot = self.stats.slots
             while ring and ring[0].slots_left <= 0:
                 self._commit_call(g, ring.popleft())
-        mark_engine_phase("other")
-
-        # 7) close this slot's device->host sync bucket (no-op unless a
-        #    repro.analysis TransferSanitizer is active)
-        mark_engine_step()
 
     def _abort_ring(self, g: int, r: int) -> None:
         """Discard (g, r)'s in-flight ring: members reroute loss-free

@@ -588,14 +588,8 @@ class MPPipelineServer(PipelineServer):
         self.monitor.register((g, r), w.proc)
         return w
 
-    def _finalize(self, call) -> None:
-        for dev, fin in call.readbacks:
-            fin(
-                dev.result()
-                if isinstance(dev, _PendingReply)
-                else host_readback(dev)
-            )
-        call.readbacks = []
+    def _read(self, dev):
+        return dev.result() if isinstance(dev, _PendingReply) else host_readback(dev)
 
     def _on_ring_abort(self, g: int, r: int) -> None:
         w = self._workers.get((g, r))

@@ -35,6 +35,10 @@ Responsibilities:
   preempts.
 * **Energy gating**: a replica only opens a call when its budget clears
   ``ReplicaBudget.can_start`` (paper: CE(PM) <= E).
+
+Admission attempts, preemptions and reroutes are the spans
+``sched.admit``, ``sched.preempt`` and ``sched.reroute``
+(:mod:`.readback`).
 """
 
 from __future__ import annotations
@@ -47,6 +51,7 @@ import numpy as np
 
 from .budget import ReplicaBudget
 from .cache import KVCacheManager
+from .readback import span
 from .router import RouteError, Router
 
 __all__ = ["Request", "StepScheduler"]
@@ -81,17 +86,18 @@ class Request:
     done: bool = False
     dropped: bool = False
     t_submit: float = 0.0  # wall clock at submit (TTFT accounting)
-    t_first_token: float | None = None  # wall clock of the first generated token
+    t_first_token: float | None = None  # wall clock: first token in ``generated``
     submit_slot: int = 0  # engine slot counter at submit
     slot_first_token: int | None = None  # slot the first token's call completed
+    slot_last_token: int | None = None  # slot the latest token landed in ``generated``
 
     @property
     def ttft(self) -> float | None:
         """Wall-clock time-to-first-token, once the first token lands.
 
-        Stamped at dispatch-observable time — the moment the producing
-        call's device slots complete — not when the async completion
-        queue drains it, so a deep in-flight ring cannot inflate TTFT.
+        Stamped as the token lands in ``generated``, after the readback
+        that brings it to the host, within the step in which a client
+        polling between steps first sees it.
         """
         if self.t_first_token is None:
             return None
@@ -213,28 +219,32 @@ class StepScheduler:
         to re-prefill), so admissions within a slot see each other's
         claims and an under-reserved re-admit cannot immediately preempt
         healthy residents. Decode growth still allocates lazily."""
-        try:
-            replicas = self.router.route(
-                self.budgets,
-                free_slots=self.free_counts(),
-                inflight=self.inflight() if self.inflight is not None else None,
-            )
-        except RouteError:
-            return False
-        ctx = req.context_len()
-        mgrs = [self.managers[(g, replicas[g])] for g in range(self.G)]
-        if any(not m.can_reserve(ctx) for m in mgrs):
-            return False
-        req.replicas = replicas
-        req.slot_ids = [m.reserve(req.rid, ctx) for m in mgrs]
-        req.cache_ready = [False] * self.G
-        req.chunk_pos = 0
-        req.chunk_outs = []
-        req.park_steps = 0
-        req.queued = False
-        self.active.append(req)
-        self.stats.peak_active = max(self.stats.peak_active, len(self.active))
-        return True
+        def args():
+            return {"rid": req.rid, "admitted": int(req.replicas is not None)}
+
+        with span("sched.admit", args):
+            try:
+                replicas = self.router.route(
+                    self.budgets,
+                    free_slots=self.free_counts(),
+                    inflight=self.inflight() if self.inflight is not None else None,
+                )
+            except RouteError:
+                return False
+            ctx = req.context_len()
+            mgrs = [self.managers[(g, replicas[g])] for g in range(self.G)]
+            if any(not m.can_reserve(ctx) for m in mgrs):
+                return False
+            req.replicas = replicas
+            req.slot_ids = [m.reserve(req.rid, ctx) for m in mgrs]
+            req.cache_ready = [False] * self.G
+            req.chunk_pos = 0
+            req.chunk_outs = []
+            req.park_steps = 0
+            req.queued = False
+            self.active.append(req)
+            self.stats.peak_active = max(self.stats.peak_active, len(self.active))
+            return True
 
     def admit_pending(self) -> None:
         """Drain the FIFO queue into freed capacity; a fully dead group
@@ -321,35 +331,41 @@ class StepScheduler:
         failure). An in-flight speculative round is rewound first
         (:meth:`rewind_spec`) — its uncommitted draft rows must not
         survive as phantom context on the stages that stay placed."""
-        self.rewind_spec(req)
-        g = req.stage
-        self.managers[(g, req.replicas[g])].release(req.rid, req.slot_ids[g])
-        req.slot_ids[g] = None
-        req.cache_ready[g] = False
-        req.chunk_pos = 0
-        req.chunk_outs = []
-        req.chunk_seq = None
-        if not any(b.alive for b in self.budgets[g]):
-            # The whole group is gone: nothing to fail over to.
-            self.drop_resident(req)
-            return
-        try:
-            new_r = self.router.reroute(
-                self.budgets,
-                g,
-                free_slots=self.free_counts(),
-                inflight=self.inflight() if self.inflight is not None else None,
-            )
-        except RouteError:
-            # Live siblings exist but are momentarily full / power-saving:
-            # the request stays parked (slotless) and the re-place is
-            # retried every slot until a sibling slot frees up.
-            return
-        req.replicas[g] = new_r
-        # Slot-only reservation: the sibling's memory grows lazily at
-        # call time (ensure_capacity), chunk by chunk in chunked mode.
-        req.slot_ids[g] = self.managers[(g, new_r)].reserve(req.rid, 0)
-        self.stats.rerouted_stages += 1
+        g, src = req.stage, req.replicas[req.stage]
+
+        def args():
+            dst = req.replicas[g] if req.slot_ids[g] is not None else -1
+            return {"rid": req.rid, "g": g, "src": src, "dst": dst}
+
+        with span("sched.reroute", args):
+            self.rewind_spec(req)
+            self.managers[(g, req.replicas[g])].release(req.rid, req.slot_ids[g])
+            req.slot_ids[g] = None
+            req.cache_ready[g] = False
+            req.chunk_pos = 0
+            req.chunk_outs = []
+            req.chunk_seq = None
+            if not any(b.alive for b in self.budgets[g]):
+                # The whole group is gone: nothing to fail over to.
+                self.drop_resident(req)
+                return
+            try:
+                new_r = self.router.reroute(
+                    self.budgets,
+                    g,
+                    free_slots=self.free_counts(),
+                    inflight=self.inflight() if self.inflight is not None else None,
+                )
+            except RouteError:
+                # Live siblings exist but are momentarily full / power-saving:
+                # the request stays parked (slotless) and the re-place is
+                # retried every slot until a sibling slot frees up.
+                return
+            req.replicas[g] = new_r
+            # Slot-only reservation: the sibling's memory grows lazily at
+            # call time (ensure_capacity), chunk by chunk in chunked mode.
+            req.slot_ids[g] = self.managers[(g, new_r)].reserve(req.rid, 0)
+            self.stats.rerouted_stages += 1
 
     def evict_stage_residents(self, g: int, r: int) -> None:
         """Replica ``(g, r)``'s device state was wiped out from under
@@ -391,7 +407,7 @@ class StepScheduler:
                 victim = self.youngest_preemptable(g, r, {req.rid})
                 if victim is None:
                     break
-                self.preempt(victim)
+                self.preempt(victim, g, r)
             if mgr.free_slots() > 0:
                 req.replicas[g] = r
                 req.slot_ids[g] = mgr.reserve(req.rid, 0)
@@ -433,7 +449,7 @@ class StepScheduler:
         ]
         return max(victims, key=lambda q: q.rid, default=None)
 
-    def preempt(self, victim: Request) -> None:
+    def preempt(self, victim: Request, g: int, r: int) -> None:
         """Evict a resident fleet-wide and requeue it. Its prompt and
         generated tokens are intact, so re-admission re-prefills the
         exact context at stage 0 — preemption loses work, not tokens.
@@ -443,29 +459,31 @@ class StepScheduler:
         head would let it re-claim the pages its preemptor just took and
         ping-pong the pool under pressure. The latency cost of waiting
         behind fresh arrivals is the trade-off (a starvation-free aging
-        policy is an open item in ROADMAP.md)."""
-        for g in range(self.G):
-            self.managers[(g, victim.replicas[g])].release(
-                victim.rid, victim.slot_ids[g]
-            )
-        self.active.remove(victim)
-        victim.replicas = None
-        victim.slot_ids = None
-        victim.cache_ready = None
-        victim.stage = 0
-        victim.hidden = None
-        victim.chunk_pos = 0
-        victim.chunk_outs = []
-        victim.chunk_seq = None
-        victim.park_steps = 0
-        # A preempted mid-round speculative request starts over: every
-        # slot and page was just released (lengths zeroed with them), so
-        # no rollback is needed — just forget the round.
-        victim.spec_drafts = None
-        victim.spec_adv = None
-        victim.queued = True
-        self.pending.append(victim)
-        self.stats.preempted_jobs += 1
+        policy is an open item in ROADMAP.md). ``(g, r)`` is the replica
+        whose memory or slot the eviction frees (the span's arguments)."""
+        with span("sched.preempt", lambda: {"rid": victim.rid, "g": g, "r": r}):
+            for k in range(self.G):
+                self.managers[(k, victim.replicas[k])].release(
+                    victim.rid, victim.slot_ids[k]
+                )
+            self.active.remove(victim)
+            victim.replicas = None
+            victim.slot_ids = None
+            victim.cache_ready = None
+            victim.stage = 0
+            victim.hidden = None
+            victim.chunk_pos = 0
+            victim.chunk_outs = []
+            victim.chunk_seq = None
+            victim.park_steps = 0
+            # A preempted mid-round speculative request starts over: every
+            # slot and page was just released (lengths zeroed with them), so
+            # no rollback is needed — just forget the round.
+            victim.spec_drafts = None
+            victim.spec_adv = None
+            victim.queued = True
+            self.pending.append(victim)
+            self.stats.preempted_jobs += 1
 
     def ensure_capacity(
         self, g: int, r: int, req: Request, need_len: int, protected: set[int]
@@ -483,7 +501,7 @@ class StepScheduler:
             victim = self.youngest_preemptable(g, r, protected)
             if victim is None:
                 return False
-            self.preempt(victim)
+            self.preempt(victim, g, r)
         return True
 
     # ------------------------------------------------------------------
