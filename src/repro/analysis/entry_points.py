@@ -89,7 +89,8 @@ def _sds(shape, dtype) -> jax.ShapeDtypeStruct:
 
 
 def _pool_sds(cfg, kv_dtype):
-    shape = (cfg.n_layers, _P + 1, _PAGE, cfg.n_kv_heads, cfg.head_dim)
+    # The serving engine's pools: page rows, a position's heads side by side.
+    shape = (cfg.n_layers, _P + 1, _PAGE, cfg.n_kv_heads * cfg.head_dim)
     pools = {"k": _sds(shape, kv_dtype), "v": _sds(shape, kv_dtype)}
     if jnp.dtype(kv_dtype) == jnp.int8:
         pools["k_scale"] = _sds(shape[:3], jnp.float32)
@@ -221,10 +222,11 @@ def _model_entries(name: str) -> list[EntryPoint]:
 
 
 def _kernel_entries() -> list[EntryPoint]:
-    """The Pallas paged kernels traced standalone: the zero-gather
-    budget binds directly at the kernel boundary."""
+    """The Pallas paged kernels traced standalone, as serving calls them
+    (one layer of a page-row pool stack): the zero-gather budget binds
+    directly at the kernel boundary."""
     B, KV, G, D = 2, 2, 2, 8
-    page, NB, C = 8, 3, 4
+    page, NB, C, L = 8, 3, 4, 2
     P = B * NB + 1
     entries: list[EntryPoint] = []
     for kernel_name, fn in PALLAS_PAGED_KERNELS.items():
@@ -233,11 +235,14 @@ def _kernel_entries() -> list[EntryPoint]:
         def make(fn=fn, prefill=prefill):
             q_shape = (B, C, KV * G, D) if prefill else (B, 1, KV * G, D)
             q = _sds(q_shape, jnp.float32)
-            k = _sds((P, page, KV, D), jnp.float32)
-            v = _sds((P, page, KV, D), jnp.float32)
+            k = _sds((L, P, page, KV * D), jnp.float32)
+            v = _sds((L, P, page, KV * D), jnp.float32)
             bt = _sds((B, NB), jnp.int32)
             idx = _sds((B,), jnp.int32)  # lengths (decode) / offsets (prefill)
-            return jax.make_jaxpr(fn)(q, k, v, bt, idx)
+            layer = _sds((), jnp.int32)
+            return jax.make_jaxpr(
+                lambda q, k, v, bt, idx, li: fn(q, k, v, bt, idx, layer=li)
+            )(q, k, v, bt, idx, layer)
 
         entries.append(
             EntryPoint(f"kernel:{kernel_name}:pallas", "kernel", kernel_name,

@@ -38,41 +38,66 @@ def dot_rows(c: int, on_cpu: bool) -> int:
     return max(c, 2) if on_cpu else c
 
 
+def page_rows(pages: jnp.ndarray, scales: jnp.ndarray | None, layer):
+    """A pool as a stack of page rows ``[L, P, page, KV * D]``, its
+    scales ``[L, P, page]`` and the layer to read.
+
+    With ``layer``, ``pages`` is a layer stack ``[L, P, page, ...]``;
+    without, one layer's pool ``[P, page, ...]``, read as a stack of one
+    at layer 0. A row is the ``KV * D`` values of one position, heads
+    side by side, kept as ``[KV, D]`` or as one axis: the same bytes in
+    row-major order, and no op at all for a pool kept as rows.
+    """
+    if layer is None:
+        pages = pages.reshape((1,) + pages.shape[:2] + (-1,))
+        scales = None if scales is None else scales[None]
+        return pages, scales, 0
+    return pages.reshape(pages.shape[:3] + (-1,)), scales, layer
+
+
 def gather_pages(
     pages: jnp.ndarray,
     block_tables: jnp.ndarray,
     scales: jnp.ndarray | None = None,
+    layer: jnp.ndarray | None = None,
+    head_dim: int | None = None,
 ) -> jnp.ndarray:
     """Materialize a contiguous cache from a page pool.
 
-    pages: [P, page, KV, D]; block_tables: [B, NB] -> [B, NB*page, KV, D].
-    With ``scales`` ([P, page] per-row fp32, int8 pools) the gathered
-    rows are dequantized: ``pages[bt] * scales[bt]``.
+    pages: one layer's pool [P, page, *row] or, with ``layer``, a layer
+    stack [L, P, page, *row] gathered at that layer straight from the
+    stack; block_tables: [B, NB] -> [B, NB*page, *row], where a row is
+    [KV, D] or [KV * D], and with ``head_dim`` [B, NB*page, KV, D]. With
+    ``scales`` ([P, page] or [L, P, page] per-row fp32, int8 pools) the
+    gathered rows are dequantized: ``pages[bt] * scales[bt]``.
     """
-    B, NB = block_tables.shape
-    _, page, KV, D = pages.shape
-    out = pages[block_tables].reshape(B, NB * page, KV, D)
+    at = (block_tables,) if layer is None else (layer, block_tables)
+    out = pages[at]  # [B, NB, page, *row]
+    B, NB, page = out.shape[:3]
+    row = out.shape[3:] if head_dim is None else (-1, head_dim)
+    out = out.reshape((B, NB * page) + row)
     if scales is None:
         return out
-    s = scales[block_tables].reshape(B, NB * page)
-    return out.astype(s.dtype) * s[:, :, None, None]
+    s = scales[at].reshape(B, NB * page)
+    return out.astype(s.dtype) * s.reshape(s.shape + (1,) * (out.ndim - 2))
 
 
 def paged_decode_attention_ref(
     q: jnp.ndarray,  # [B, 1, H, D] (model layout)
-    k_pages: jnp.ndarray,  # [P, page, KV, D]
+    k_pages: jnp.ndarray,  # one layer's [P, page, KV, D]; with layer, [L, P, page, ...]
     v_pages: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, NB] int32
     lengths: jnp.ndarray,  # [B] int32, valid entries incl. current token
     *,
+    layer: jnp.ndarray | None = None,
     window: int | None = None,
-    k_scales: jnp.ndarray | None = None,  # [P, page] fp32 (int8 pools)
+    k_scales: jnp.ndarray | None = None,  # [(L,) P, page] fp32 (int8 pools)
     v_scales: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Gather-then-attend oracle for the paged kernel. Returns [B,1,H,D]."""
     B, _, H, D = q.shape
-    k = gather_pages(k_pages, block_tables, k_scales)  # [B, S, KV, D]
-    v = gather_pages(v_pages, block_tables, v_scales)
+    k = gather_pages(k_pages, block_tables, k_scales, layer, D)  # [B, S, KV, D]
+    v = gather_pages(v_pages, block_tables, v_scales, layer, D)
     return decode_attention_ref(
         q.transpose(0, 2, 1, 3),
         k.transpose(0, 2, 1, 3),
@@ -84,12 +109,13 @@ def paged_decode_attention_ref(
 
 def paged_prefill_attention(
     q: jnp.ndarray,  # [B, C, H, D] (model layout) — C new tokens per lane
-    k_pages: jnp.ndarray,  # [P, page, KV, D]
+    k_pages: jnp.ndarray,  # one layer's [P, page, KV, D]; with layer, [L, P, page, ...]
     v_pages: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, NB] int32
     offsets: jnp.ndarray,  # [B] int32 absolute position of q[:, 0]
     *,
-    k_scales: jnp.ndarray | None = None,  # [P, page] fp32 (int8 pools)
+    layer: jnp.ndarray | None = None,
+    k_scales: jnp.ndarray | None = None,  # [(L,) P, page] fp32 (int8 pools)
     v_scales: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Prefill-over-paged-prefix attention — the gather fallback.
@@ -104,11 +130,13 @@ def paged_prefill_attention(
     replaces it behind this signature on TPU, and this fallback stays as
     the off-TPU path and test oracle. Query ``i`` of lane ``b`` attends
     positions ``<= offsets[b] + i``; rows past the caller's valid count
-    produce garbage that the engine discards. Returns [B, C, H, D].
+    produce garbage that the engine discards. The lanes' pages are read
+    from layer ``layer`` of the pool stack, as the kernel reads them.
+    Returns [B, C, H, D].
     """
     B, C_out, H, D = q.shape
-    k = gather_pages(k_pages, block_tables, k_scales)  # [B, S, KV, D]
-    v = gather_pages(v_pages, block_tables, v_scales)
+    k = gather_pages(k_pages, block_tables, k_scales, layer, D)  # [B, S, KV, D]
+    v = gather_pages(v_pages, block_tables, v_scales, layer, D)
     S, KV = k.shape[1], k.shape[2]
     G = H // KV
     scale = D**-0.5

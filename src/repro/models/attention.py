@@ -305,26 +305,32 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _per_head_shard(kernel, q, k, v, *rest):
+def _per_head_shard(kernel, q, k, v, *rest, rows: bool = False):
     """Call a Pallas attention kernel under the ambient serving mesh.
 
     XLA cannot partition a Mosaic kernel. When a ``model`` mesh axis is
     in scope (the engine enters its replica's abstract mesh around each
     dispatch), the kernel runs under ``shard_map``: q [B, S, H, D] and
-    k/v ([B, S, KV, D] caches or [P, page, KV, D] pools) split over
-    their head axis, every other operand replicated. Heads that do not
-    divide the axis run whole on every device. Without a mesh this is a
-    plain call.
+    k/v split over their heads, every other operand replicated. k/v are
+    [..., KV, D] (caches, or pools whose rows keep the head axis) or,
+    with ``rows``, page-row pools [..., KV * D], whose last axis holds
+    the heads side by side and splits into whole heads. Heads that do
+    not divide the axis run whole on every device. Without a mesh this
+    is a plain call.
     """
     mesh = jax.sharding.get_abstract_mesh()
     width = 1 if mesh.empty else dict(mesh.shape).get("model", 1)
     if width == 1:
         return kernel(q, k, v, *rest)
-    split = q.shape[2] % width == 0 and k.shape[2] % width == 0
-    heads = P(None, None, "model" if split else None, None)
+    kv = k.shape[-1] // q.shape[-1] if rows else k.shape[-2]
+    axis = "model" if q.shape[2] % width == 0 and kv % width == 0 else None
+    heads = P(None, None, axis, None)
+    kv_heads = P(*(None,) * (k.ndim - 1), axis) if rows else (
+        P(*(None,) * (k.ndim - 2), axis, None)
+    )
     return jax.shard_map(
         kernel,
-        in_specs=(heads, heads, heads) + (P(),) * len(rest),
+        in_specs=(heads, kv_heads, kv_heads) + (P(),) * len(rest),
         out_specs=heads,
         check_vma=False,
     )(q, k, v, *rest)
@@ -400,35 +406,47 @@ def attention_block(
     return o, (k_cache, v_cache)
 
 
+def _pool_index(layer, write_pages, write_offs) -> tuple:
+    """Where a layer's K/V rows land: ``[layer, page, offset]`` of a
+    layer stack, or ``[page, offset]`` of one layer's pool (``layer``
+    None)."""
+    at = (write_pages, write_offs)
+    return at if layer is None else (layer,) + at
+
+
 def _scatter_kv_pages(
-    pages: dict, k: jax.Array, v: jax.Array, write_pages, write_offs
+    pages: dict, at: tuple, k: jax.Array, v: jax.Array
 ) -> dict:
-    """Write K/V rows into the shared pool at (write_pages, write_offs).
+    """Write K/V rows into the pool at ``at`` (:func:`_pool_index`).
 
     ``pages``: {"k", "v"} (+ {"k_scale", "v_scale"} for int8 pools —
-    the presence of scales *is* the quantization switch). k/v rows are
-    [..., KV, Dh]; int8 pools quantize each row at scatter time
-    (per-row amax, :func:`repro.kernels.decode_attention.quantize_kv`)
-    and store its fp32 scale alongside, so a row is quantized exactly
-    once and never requantized.
+    the presence of scales *is* the quantization switch). A layer
+    stack is the layer loop's carry, so its rows land in place. k/v
+    rows are [..., KV, Dh], written in the pool's row shape: [KV, Dh],
+    or [KV * Dh] for a pool kept as page rows (the serving engine's).
+    int8 pools quantize each row at scatter time (per-row amax,
+    :func:`repro.kernels.decode_attention.quantize_kv`) and store its
+    fp32 scale alongside, so a row is quantized exactly once and never
+    requantized.
     """
+
+    def put(pool, rows):
+        rows = rows.reshape(rows.shape[: rows.ndim - 2] + pool.shape[len(at):])
+        return pool.at[at].set(rows.astype(pool.dtype))
+
     out = dict(pages)
     if "k_scale" in pages:
         from ..kernels.decode_attention import quantize_kv
 
         qk, ks = quantize_kv(k)
         qv, vs = quantize_kv(v)
-        out["k"] = pages["k"].at[write_pages, write_offs].set(qk)
-        out["v"] = pages["v"].at[write_pages, write_offs].set(qv)
-        out["k_scale"] = pages["k_scale"].at[write_pages, write_offs].set(ks)
-        out["v_scale"] = pages["v_scale"].at[write_pages, write_offs].set(vs)
+        out["k"] = put(pages["k"], qk)
+        out["v"] = put(pages["v"], qv)
+        out["k_scale"] = pages["k_scale"].at[at].set(ks)
+        out["v_scale"] = pages["v_scale"].at[at].set(vs)
     else:
-        out["k"] = pages["k"].at[write_pages, write_offs].set(
-            k.astype(pages["k"].dtype)
-        )
-        out["v"] = pages["v"].at[write_pages, write_offs].set(
-            v.astype(pages["v"].dtype)
-        )
+        out["k"] = put(pages["k"], k)
+        out["v"] = put(pages["v"], v)
     return out
 
 
@@ -438,36 +456,42 @@ def paged_attention_block(
     cfg: ModelConfig,
     *,
     positions: jax.Array,  # [B, 1] per-request absolute position (>= 0)
-    pages: dict,  # {"k","v"[,"k_scale","v_scale"]} shared pool (one layer)
+    pages: dict,  # {"k","v"[,"k_scale","v_scale"]} pool stack (or one layer)
     block_tables: jax.Array,  # [B, NB] int32
     write_pages: jax.Array,  # [B] physical page for this token's K/V
     write_offs: jax.Array,  # [B] offset within that page
+    layer: jax.Array | None = None,  # int32 scalar: this layer of the stack
 ):
     """Single-token attention sub-block against a paged KV pool.
 
     The batch dimension is the engine's slot width: every request has
     its own context length (``positions``) and block table. The new
-    token's K/V land at (write_pages, write_offs), precomputed by
-    :func:`repro.models.transformer.decode_step_paged` (layer-invariant;
-    masked lanes point at the pool's scratch page so a batched scatter
-    never corrupts a live page). int8 pools (``k_scale`` present)
-    quantize at scatter and dequantize inside the page gather — kernel
-    and fallback alike. Returns (out [B,1,D], updated pages).
+    token's K/V land at (layer, write_pages, write_offs) of the pool
+    stack, precomputed by :func:`repro.models.transformer.decode_step_paged`
+    (layer-invariant; masked lanes point at the pool's scratch page so a
+    batched scatter never corrupts a live page), and attention reads
+    layer ``layer`` of the stack in place; with ``layer`` None, ``pages``
+    is one layer's pool. int8 pools (``k_scale`` present) quantize at
+    scatter and dequantize inside the page gather — kernel and fallback
+    alike. Returns (out [B,1,D], updated pages).
     """
     dtype = cfg.compute_dtype
     q, k, v = _project_qkv(x, p, cfg, positions)
-    pages = _scatter_kv_pages(pages, k[:, 0], v[:, 0], write_pages, write_offs)
+    at = _pool_index(layer, write_pages, write_offs)
+    pages = _scatter_kv_pages(pages, at, k[:, 0], v[:, 0])
+    kp, vp = pages["k"], pages["v"]
+    ks, vs = pages.get("k_scale"), pages.get("v_scale")
     attn_len = positions[:, 0] + 1  # valid entries incl. the new token
     if cfg.attn_impl == "pallas":
         from ..kernels.decode_attention import paged_decode_attention
 
         out = _per_head_shard(
-            lambda q, k, v, bt, n, ks, vs: paged_decode_attention(
-                q, k, v, bt, n, k_scales=ks, v_scales=vs,
+            lambda q, k, v, bt, n, li, ks, vs: paged_decode_attention(
+                q, k, v, bt, n, layer=li, k_scales=ks, v_scales=vs,
                 interpret=_use_interpret(),
             ),
-            q, pages["k"], pages["v"], block_tables, attn_len,
-            pages.get("k_scale"), pages.get("v_scale"),
+            q, kp, vp, block_tables, attn_len, layer, ks, vs,
+            rows=kp.ndim == len(at) + 1,
         )
     elif cfg.decode_mulsum or cfg.attn_kv_stream:
         # The dense-decode perf variants over the gathered pages
@@ -475,10 +499,10 @@ def paged_attention_block(
         # the position row).
         from ..kernels.decode_attention import gather_pages
 
-        k_cache = gather_pages(pages["k"], block_tables, pages.get("k_scale"))
-        v_cache = gather_pages(pages["v"], block_tables, pages.get("v_scale"))
+        Dh = cfg.head_dim
         out = decode_attention(
-            q, k_cache, v_cache, attn_len[:, None],
+            q, gather_pages(kp, block_tables, ks, layer, Dh),
+            gather_pages(vp, block_tables, vs, layer, Dh), attn_len[:, None],
             mulsum=cfg.decode_mulsum, kv_stream=cfg.attn_kv_stream,
         )
     else:
@@ -488,8 +512,8 @@ def paged_attention_block(
         from ..kernels.decode_attention import paged_prefill_attention
 
         out = paged_prefill_attention(
-            q, pages["k"], pages["v"], block_tables, positions[:, 0],
-            k_scales=pages.get("k_scale"), v_scales=pages.get("v_scale"),
+            q, kp, vp, block_tables, positions[:, 0],
+            layer=layer, k_scales=ks, v_scales=vs,
         )
     o = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dtype))
     return o, pages
@@ -539,45 +563,51 @@ def paged_chunk_attention_block(
     cfg: ModelConfig,
     *,
     positions: jax.Array,  # [B, C] absolute position per chunk token
-    pages: dict,  # {"k","v"[,"k_scale","v_scale"]} shared pool (one layer)
+    pages: dict,  # {"k","v"[,"k_scale","v_scale"]} pool stack (or one layer)
     block_tables: jax.Array,  # [B, NB] int32
     write_pages: jax.Array,  # [B, C] physical page per chunk token
     write_offs: jax.Array,  # [B, C] offset within that page
+    layer: jax.Array | None = None,  # int32 scalar: this layer of the stack
 ):
     """Chunked-prefill sub-block against a paged KV pool.
 
     The paged sibling of :func:`chunk_attention_block`: the chunk's K/V
-    are scattered into each request's reserved pages (masked lanes and
-    padding positions land on the scratch page, precomputed by
+    are scattered into each request's reserved pages of layer ``layer``
+    of the pool stack, or of one layer's pool with ``layer`` None
+    (masked lanes and padding positions land on the
+    scratch page, precomputed by
     :func:`repro.models.transformer.prefill_chunk_paged`; int8 pools
     quantize per row at scatter), then the chunk attends over the paged
     prefix. On the Pallas path that is
     :func:`repro.kernels.decode_attention.paged_prefill_attention_pallas`
     — the block-table walk happens in the kernel's DMA index map, so no
-    contiguous copy of the prefix is ever materialized; off TPU the
-    gather fallback computes the identical masked softmax. Returns
-    (out [B, C, D], updated pages).
+    contiguous copy of the prefix, nor a slice of the layer, is ever
+    materialized; off TPU the gather fallback computes the identical
+    masked softmax. Returns (out [B, C, D], updated pages).
     """
     dtype = cfg.compute_dtype
     q, k, v = _project_qkv(x, p, cfg, positions)
-    pages = _scatter_kv_pages(pages, k, v, write_pages, write_offs)
+    at = _pool_index(layer, write_pages, write_offs)
+    pages = _scatter_kv_pages(pages, at, k, v)
+    kp, vp = pages["k"], pages["v"]
+    ks, vs = pages.get("k_scale"), pages.get("v_scale")
     if cfg.attn_impl == "pallas":
         from ..kernels.decode_attention import paged_prefill_attention_pallas
 
         out = _per_head_shard(
-            lambda q, k, v, bt, off, ks, vs: paged_prefill_attention_pallas(
-                q, k, v, bt, off, k_scales=ks, v_scales=vs,
+            lambda q, k, v, bt, off, li, ks, vs: paged_prefill_attention_pallas(
+                q, k, v, bt, off, layer=li, k_scales=ks, v_scales=vs,
                 interpret=_use_interpret(),
             ),
-            q, pages["k"], pages["v"], block_tables, positions[:, 0],
-            pages.get("k_scale"), pages.get("v_scale"),
+            q, kp, vp, block_tables, positions[:, 0], layer, ks, vs,
+            rows=kp.ndim == len(at) + 1,
         )
     else:
         from ..kernels.decode_attention import paged_prefill_attention
 
         out = paged_prefill_attention(
-            q, pages["k"], pages["v"], block_tables, positions[:, 0],
-            k_scales=pages.get("k_scale"), v_scales=pages.get("v_scale"),
+            q, kp, vp, block_tables, positions[:, 0],
+            layer=layer, k_scales=ks, v_scales=vs,
         )
     o = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dtype))
     return o, pages

@@ -563,10 +563,16 @@ def decode_step_paged(
     write coordinates are derived in-graph.
 
     token: [W, 1] ids (stage 0) or hidden [W, 1, D] (later stages);
-    pools: {"k": [n_layers, P+1, page, KV, Dh], "v": ...} — int8 pools
-    additionally carry {"k_scale", "v_scale": [n_layers, P+1, page]}
-    per-row fp32 scales (quantized at scatter, dequantized in the page
-    gather). Returns (logits/hidden [W, 1, V|D], updated pools).
+    pools: {"k": [n_layers, P+1, page, KV * Dh], "v": ...} page rows, as
+    the serving engine keeps them (or [n_layers, P+1, page, KV, Dh], the
+    same bytes) — int8 pools additionally carry {"k_scale", "v_scale":
+    [n_layers, P+1, page]} per-row fp32 scales (quantized at scatter,
+    dequantized in the page gather). The layer loop carries the pool
+    stack: layer ``l`` scatters its rows in place at ``[l, page, offset]``
+    and attention reads layer ``l`` of the stack by index, so no layer of
+    the pool is sliced out or copied back, and a caller that donates
+    ``pools`` gets them updated in place. Returns (logits/hidden
+    [W, 1, V|D], updated pools).
     """
     if not supports_paged(cfg):
         raise ValueError(f"{cfg.name}: paged decode needs uniform full attention")
@@ -586,22 +592,28 @@ def decode_step_paged(
     write_offs = pos % page
     p_run = params["classes"]["c0"]
 
-    def body(x, scanned):
-        p_layer, pages = scanned
+    def body(carry, scanned):
+        x, pools = carry
+        p_layer, layer = scanned
         h = rmsnorm(x, p_layer["ln1"], cfg.rms_eps)
-        a, pages = paged_attention_block(
+        a, pools = paged_attention_block(
             h, p_layer["attn"], cfg,
-            positions=positions, pages=pages,
+            positions=positions, pages=pools, layer=layer,
             block_tables=block_tables,
             write_pages=write_pages, write_offs=write_offs,
         )
         x = x + a
         h2 = rmsnorm(x, p_layer["ln2"], cfg.rms_eps)
         ff, _ = _ffn(h2, p_layer, cfg)
-        return x + ff, pages
+        return (x + ff, pools), None
 
-    x, pools = jax.lax.scan(body, x, (p_run, pools))
+    (x, pools), _ = jax.lax.scan(body, (x, pools), (p_run, _layer_ids(pools)))
     return _unembed(params, x, cfg), pools
+
+
+def _layer_ids(pools: dict) -> jax.Array:
+    """The layer index of each step of a paged layer loop."""
+    return jnp.arange(pools["k"].shape[0], dtype=jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -669,12 +681,16 @@ def prefill_chunk_paged(
     reserved pages — write coordinates come from the block table, masked
     lanes (``offsets == -1``) and padding positions (``>= valids``) land
     on the scratch page — and the chunk attends over the paged prefix
-    through the gather fallback in :mod:`repro.kernels.decode_attention`.
+    through the paged kernel, or off the Pallas path its gather fallback
+    (:mod:`repro.kernels.decode_attention`).
 
     chunk: [W, C] ids (stage 0) or [W, C, D] hidden; offsets [W] int32
     (tokens already in context; -1 = masked lane); valids [W] int32;
-    pools: {"k": [n_layers, P+1, page, KV, Dh], "v": ...} — int8 pools
-    additionally carry per-row fp32 scales (see :func:`decode_step_paged`).
+    pools: {"k": [n_layers, P+1, page, KV * Dh], "v": ...} (see
+    :func:`decode_step_paged`) — int8 pools additionally carry per-row
+    fp32 scales.
+    The layer loop carries the pool stack and each layer writes and reads
+    its own layer of it in place, as in :func:`decode_step_paged`.
     Returns ([W, C, V|D] per-position outputs, updated pools).
     """
     if not supports_paged(cfg):
@@ -701,21 +717,22 @@ def prefill_chunk_paged(
     write_offs = positions % page
     p_run = params["classes"]["c0"]
 
-    def body(x, scanned):
-        p_layer, pages = scanned
+    def body(carry, scanned):
+        x, pools = carry
+        p_layer, layer = scanned
         h = rmsnorm(x, p_layer["ln1"], cfg.rms_eps)
-        a, pages = paged_chunk_attention_block(
+        a, pools = paged_chunk_attention_block(
             h, p_layer["attn"], cfg,
-            positions=positions, pages=pages,
+            positions=positions, pages=pools, layer=layer,
             block_tables=block_tables,
             write_pages=write_pages, write_offs=write_offs,
         )
         x = x + a
         h2 = rmsnorm(x, p_layer["ln2"], cfg.rms_eps)
         ff, _ = _ffn(h2, p_layer, cfg)
-        return x + ff, pages
+        return (x + ff, pools), None
 
-    x, pools = jax.lax.scan(body, x, (p_run, pools))
+    (x, pools), _ = jax.lax.scan(body, (x, pools), (p_run, _layer_ids(pools)))
     return _unembed(params, x, cfg), pools
 
 

@@ -683,12 +683,10 @@ class _PagedExec:
 
             def rows(leaf):
                 # leaf: [N, n_layers, 1, NBs*ps, KV, Dh] -> page rows
-                # [n_layers, N*NBs, ps, KV, Dh]
+                # [n_layers, N*NBs, ps, KV * Dh]
                 n = leaf.shape[1]
                 x = leaf[:, :, 0].reshape(N, n, NBs, ps, *leaf.shape[4:])
-                return x.transpose(1, 0, 2, 3, 4, 5).reshape(
-                    n, N * NBs, ps, *leaf.shape[4:]
-                )
+                return x.transpose(1, 0, 2, 3, 4, 5).reshape(n, N * NBs, ps, -1)
 
             new = dict(pools)
             new["k"] = pools["k"].at[:, flat].set(
@@ -750,15 +748,16 @@ class _PagedExec:
             self.verify_fn = verify_fn
 
     def init_cache(self, r):
-        """Shared page pool: [n_layers, P+1, page, KV, Dh] (page index P
-        is the scratch page for masked lanes). ``kv_dtype="int8"`` pools
+        """Shared page pool: [n_layers, P+1, page, KV * Dh] (page index P
+        is the scratch page for masked lanes; a page row holds its KV
+        heads side by side, the layout the TPU tiles without padding and
+        the paged kernel reads in place). ``kv_dtype="int8"`` pools
         store int8 entries plus one fp32 scale per page row (init 1.0 so
         untouched rows dequantize to 0)."""
         s = self.server
         c = self.model_g.cfg
         shape = (
-            c.n_layers, s.max_pages + 1, s.page_size,
-            c.n_kv_heads, c.head_dim,
+            c.n_layers, s.max_pages + 1, s.page_size, c.n_kv_heads * c.head_dim,
         )
         pools = {
             "k": jnp.zeros(shape, s.kv_dtype),

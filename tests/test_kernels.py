@@ -133,6 +133,52 @@ class TestPagedAttention:
         ref = paged_prefill_attention(q, kp, vp, bt, offsets, **scales)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=3e-5, atol=3e-5)
 
+    @pytest.mark.parametrize("rows", [True, False], ids=["rows", "heads"])
+    @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+    @pytest.mark.parametrize("KV,G,C", [(32, 1, 1), (8, 4, 5)], ids=["mha", "gqa"])
+    def test_stacked_pool_reads_its_layer(self, KV, G, C, kv_dtype, rows):
+        """Over a layer stack, the kernel at layer ``l`` is the kernel
+        over ``pool[l]`` bit for bit, and the fallback over the stack is
+        the fallback over ``pool[l]``. The stack is kept as page rows
+        ``[L, P, page, KV * D]`` (the engine's) or ``[L, P, page, KV, D]``;
+        11 pages put the scratch page in a partial block of int8 scales."""
+        from repro.kernels.decode_attention import (
+            paged_attention,
+            paged_prefill_attention,
+            quantize_kv,
+        )
+
+        L, layer, B, NB, D, page = 3, 1, 2, 5, 64, 16
+        P = B * NB + 1
+        ks = jax.random.split(jax.random.PRNGKey(KV + C), 3)
+        q = rand(ks[0], (B, C, KV * G, D), jnp.float32)
+        kp = rand(ks[1], (L, P, page, KV, D), jnp.float32)
+        vp = rand(ks[2], (L, P, page, KV, D), jnp.float32)
+        stack, one = {}, {}
+        if kv_dtype == "int8":
+            kp, stack["k_scales"] = quantize_kv(kp)
+            vp, stack["v_scales"] = quantize_kv(vp)
+            one = {name: s[layer] for name, s in stack.items()}
+        else:
+            kp, vp = kp.astype(kv_dtype), vp.astype(kv_dtype)
+        k_one, v_one = kp[layer], vp[layer]
+        if rows:
+            kp = kp.reshape(L, P, page, KV * D)
+            vp = vp.reshape(L, P, page, KV * D)
+        # The scratch page (P - 1) and the page before it in the table.
+        bt = jnp.asarray(np.random.default_rng(KV).permutation(P)[: B * NB])
+        bt = bt.at[0].set(P - 1).reshape(B, NB).astype(jnp.int32)
+        offsets = jnp.asarray([page - C, NB * page - C], jnp.int32)
+        li = jnp.int32(layer)
+
+        got = paged_attention(q, kp, vp, bt, offsets, layer=li, interpret=True, **stack)
+        want = paged_attention(q, k_one, v_one, bt, offsets, interpret=True, **one)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        ref = paged_prefill_attention(q, kp, vp, bt, offsets, layer=li, **stack)
+        ref_one = paged_prefill_attention(q, k_one, v_one, bt, offsets, **one)
+        np.testing.assert_array_equal(np.asarray(ref), np.asarray(ref_one))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=3e-5, atol=3e-5)
+
 
 class TestRMSNorm:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -183,3 +183,105 @@ class TestPallasChunkModelParity:
             np.asarray(ref_logits[0, -1]),
             rtol=2e-4, atol=2e-4,
         )
+
+
+def _scan_formulation(kind, params, inp, pools, lens_or_offs, valids, bt, cfg):
+    """The paged layer loop as a scan over the pool stack: each layer's
+    pool sliced out as ``xs`` and the updated layers stacked as ``ys``.
+    The same write coordinates as the model; the reference for the loop
+    that carries the stack and updates it in place."""
+    from repro.models import transformer as T
+    from repro.models.attention import paged_attention_block
+
+    x = T._embed(params, inp, cfg)
+    W = x.shape[0]
+    page, scratch = pools["k"].shape[2], pools["k"].shape[1] - 1
+    pos0 = jnp.maximum(lens_or_offs, 0)
+    if kind == "decode":
+        positions = pos0[:, None]
+        write_pages = jnp.where(
+            lens_or_offs >= 0, bt[jnp.arange(W), pos0 // page], scratch
+        )
+        block = paged_attention_block
+    else:
+        C = x.shape[1]
+        positions = pos0[:, None] + jnp.arange(C, dtype=jnp.int32)
+        writable = (lens_or_offs >= 0)[:, None] & (
+            jnp.arange(C)[None, :] < valids[:, None]
+        )
+        rows = jnp.arange(W, dtype=jnp.int32)[:, None]
+        table = bt[rows, jnp.minimum(positions // page, bt.shape[1] - 1)]
+        write_pages = jnp.where(writable, table, scratch)
+        block = paged_chunk_attention_block
+
+    def body(x, scanned):
+        p_layer, pages = scanned
+        h = T.rmsnorm(x, p_layer["ln1"], cfg.rms_eps)
+        a, pages = block(
+            h, p_layer["attn"], cfg, positions=positions, pages=pages,
+            block_tables=bt, write_pages=write_pages,
+            write_offs=positions[:, 0] % page if kind == "decode" else positions % page,
+        )
+        x = x + a
+        ff, _ = T._ffn(T.rmsnorm(x, p_layer["ln2"], cfg.rms_eps), p_layer, cfg)
+        return x + ff, pages
+
+    x, pools = jax.lax.scan(body, x, (params["classes"]["c0"], pools))
+    return T._unembed(params, x, cfg), pools
+
+
+class TestStackedPoolLoop:
+    """The layer loop carries the pool stack and each layer scatters
+    into, and attends over, its own layer in place: outputs and pools
+    equal the scan-over-layers formulation bit for bit, for a pool kept
+    as page rows ``[L, P+1, page, KV * Dh]`` (the engine's)."""
+
+    @pytest.mark.parametrize(
+        "impl,kv", [("xla", "float32"), ("pallas", "float32"), ("pallas", "int8")],
+        ids=["xla", "pallas", "pallas-int8"],
+    )
+    @pytest.mark.parametrize("kind", ["decode", "chunk"])
+    def test_matches_scan_formulation(self, kind, impl, kv):
+        from repro.models import build_model
+
+        cfg, _, params = tiny_model()
+        cfg = dataclasses.replace(cfg, attn_impl=impl)
+        model = build_model(cfg)
+        rng = np.random.default_rng(7)
+        W, NB, page, C = 3, 3, 8, 4
+        P = W * NB + 2
+        L, KV, Dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        heads = {}
+        for name in ("k", "v"):
+            rows = rng.normal(size=(L, P + 1, page, KV, Dh)).astype(np.float32)
+            heads[name] = jnp.asarray(rows)
+        if kv == "int8":
+            for name in ("k", "v"):
+                heads[name], heads[f"{name}_scale"] = quantize_kv(heads[name])
+        bt = jnp.asarray(rng.permutation(P)[: W * NB].reshape(W, NB).astype(np.int32))
+        if kind == "decode":
+            inp = jnp.asarray(rng.integers(0, cfg.vocab_size, (W, 1)), jnp.int32)
+            lens = jnp.asarray([5, -1, NB * page - 1], jnp.int32)
+            valids = None
+            step = lambda pools: model.decode_paged(params, inp, pools, lens, bt)
+        else:
+            inp = jnp.asarray(rng.integers(0, cfg.vocab_size, (W, C)), jnp.int32)
+            lens = jnp.asarray([3, -1, NB * page - C], jnp.int32)
+            valids = jnp.asarray([C, 0, C - 1], jnp.int32)
+            step = lambda pools: model.prefill_chunk_paged(
+                params, inp, pools, lens, valids, bt
+            )
+        as_rows = {
+            name: a.reshape(a.shape[:3] + (-1,)) if name in ("k", "v") else a
+            for name, a in heads.items()
+        }
+        out, pools = jax.jit(step)(as_rows)
+        ref_out, ref_pools = jax.jit(
+            lambda pools: _scan_formulation(kind, params, inp, pools, lens, valids, bt, cfg)
+        )(heads)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
+        assert set(pools) == set(ref_pools)
+        for name, a in pools.items():
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(ref_pools[name]).reshape(a.shape)
+            )

@@ -6,7 +6,9 @@ compiler refuses, so these tests compile each kernel for a described
 stablelm-1.6b (32 MHA heads of 64, d_model 2048) for attention and
 rmsnorm, falcon-mamba-7b (d_inner 8192, state 16) for the selective scan.
 Each asserts that the compiled program holds the Mosaic kernel
-(``tpu_custom_call``), not an XLA fallback.
+(``tpu_custom_call``), not an XLA fallback. One serving stage's paged
+decode and chunk programs are compiled whole, at the benchmark cell's
+sizes, to show that they update the donated KV pool in place.
 
 The topology is described only inside the module fixture: loading the
 TPU library is a per-process lock, and the test workers import every
@@ -14,6 +16,7 @@ test file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -150,3 +153,93 @@ def test_selective_scan(one_chip):
         ((MAMBA_DIN, MAMBA_N), jnp.float32),
     )
     assert "tpu_custom_call" in text
+
+
+# One stage of the benchmark cell: stablelm-1.6b in 3 stages of 8 layers,
+# 1,024 pages of 16 (+ the scratch page), 8 lanes, 256-token chunks.
+STAGE_PAGES, STAGE_PAGE, STAGE_W, STAGE_NB = 1025, 16, 8, 256
+
+
+def _stage_program(kind, kv_dtype, sharding):
+    """The compiled decode or chunk program of a middle stage, its pool
+    donated, as the serving engine dispatches it."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.models.common import abstract_params
+    from repro.serving.partition import stage_configs
+
+    full = dataclasses.replace(
+        get_config("stablelm-1.6b"), dtype="bfloat16", param_dtype="bfloat16",
+        attn_impl="pallas",
+    )
+    cfg = stage_configs(full, 3)[1]
+    model = build_model(cfg)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype), sharding=sharding)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), abstract_params(model.template, cfg.param_dtype)
+    )
+    shape = (cfg.n_layers, STAGE_PAGES, STAGE_PAGE, cfg.n_kv_heads * cfg.head_dim)
+    pools = {"k": sds(shape, kv_dtype), "v": sds(shape, kv_dtype)}
+    if kv_dtype == "int8":
+        pools["k_scale"] = sds(shape[:3], jnp.float32)
+        pools["v_scale"] = sds(shape[:3], jnp.float32)
+    lanes = sds((STAGE_W,), jnp.int32)
+    bt = sds((STAGE_W, STAGE_NB), jnp.int32)
+    if kind == "decode":
+        fn = jax.jit(model.decode_paged, donate_argnums=(2,))
+        args = (params, sds((STAGE_W, 1, cfg.d_model), cfg.dtype), pools, lanes, bt)
+    else:
+        fn = jax.jit(model.prefill_chunk_paged, donate_argnums=(2,))
+        x = sds((STAGE_W, CHUNK, cfg.d_model), cfg.dtype)
+        args = (params, x, pools, lanes, lanes, bt)
+    pool_shapes = {
+        shape,
+        shape[1:],
+        shape[:3] + (cfg.n_kv_heads, cfg.head_dim),
+        shape[1:3] + (cfg.n_kv_heads, cfg.head_dim),
+    }
+    return fn.lower(*args).compile(), pool_shapes, pools["k"]
+
+
+def _pool_moves(text, pool_shapes):
+    """Copies, slices and slice updates in the optimized HLO (fusion
+    bodies included) whose result is a pool or one layer of it."""
+    hits = []
+    op = re.compile(r"= \w+\[([0-9,]+)\](?:\{[^}]*\})? ([\w-]+)\(")
+    for line in text.splitlines():
+        m = op.search(line)
+        if not m or m.group(2) not in (
+            "copy", "copy-start", "dynamic-slice", "dynamic-update-slice"
+        ):
+            continue
+        dims = tuple(int(d) for d in m.group(1).split(","))
+        while dims and dims[0] == 1:
+            dims = dims[1:]
+        if dims in pool_shapes:
+            hits.append(line.strip())
+    return hits
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_stage_program_updates_the_pool_in_place(one_chip, monkeypatch, kind, kv_dtype):
+    """The layer loop carries the pool stack: no copy, slice or slice
+    update of a pool or of one of its layers is left in the program, the
+    Mosaic kernel reads the stack, and the program's temporaries stay
+    below one pool (the scan over pool layers needed about two pools)."""
+    import repro.models.attention as attention
+
+    # Off the chip the model runs its kernels in interpret mode; this
+    # compile is for the chip, where they run as Mosaic kernels.
+    monkeypatch.setattr(attention, "_use_interpret", lambda: False)
+    compiled, pool_shapes, pool = _stage_program(kind, kv_dtype, one_chip)
+    text = compiled.as_text()
+    assert _pool_moves(text, pool_shapes) == []
+    assert "tpu_custom_call" in text
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
