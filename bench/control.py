@@ -7,10 +7,11 @@ load for ``--seconds`` (the drain finishes the longest requests), judged
 twice on the same sample of served streams by the run's own verdict
 (``bench.run.verdict``): once with the served tokens (the program's
 reading, which has to be correct) and once with the tokens that the
-float8 control (:mod:`bench.reference` with ``control=True``) puts first
-in their place (the control's, which has to be not correct). One JSON
-line per seed. The limit lies above the largest program reading over a
-dozen seeds and below the smallest control reading (``PERF.md``).
+float8 control (the architecture's ``reference_logits`` with
+``control=True``) puts first in their place (the control's, which has
+to be not correct). One JSON line per seed. The limit lies above the
+largest program reading over a dozen seeds and below the smallest
+control reading (``PERF.md``).
 Benchmark runs never run the control.
 """
 

@@ -2,7 +2,8 @@
 (``kernels/decode_attention/paged.py``): the least time the chip needs for
 the attention work the clients received in the traced interval (the
 larger of its FLOPs over the peak FLOP/s and its bytes over the peak
-bandwidth, :mod:`bench.work`), over the kernel's device time there."""
+bandwidth, as the architecture module's ``token_work`` counts them), over
+the kernel's device time there."""
 
 UNIT = "%"
 LAYER = "attention kernel"

@@ -1,7 +1,8 @@
 """Model FLOP utilization of the stage programs: the model FLOPs of the
-work the clients received in the traced interval (:mod:`bench.work`),
-over the device time of the decode and chunked-prefill programs there
-times the chip's peak bf16 FLOP/s."""
+work the clients received in the traced interval (the configuration's
+architecture module's ``token_work``), over the device time of the
+decode and chunked-prefill programs there times the chip's peak bf16
+FLOP/s."""
 
 UNIT = "%"
 LAYER = "model step"

@@ -1,12 +1,15 @@
-"""The plain reference: a float32 forward pass of the served architecture.
+"""The plain reference: what every architecture module's float32 forward
+pass shares.
 
-Straight ``jax.numpy`` at ``Precision.HIGHEST``: token table, then per
-layer RMSNorm, attention with rotary positions (split halves, the
-configuration's theta, every head dimension rotated) and grouped key/value
-heads, residual, RMSNorm, SwiGLU, residual; a final RMSNorm and the head
-(the token table when tied). It imports nothing of the serving program and
-makes its weights again from the seed, layer by layer
-(:mod:`bench.weights`), so it fits beside nothing the program holds.
+Straight ``jax.numpy`` at ``Precision.HIGHEST``: the token table, the
+architecture's layers, a final RMSNorm and the head (the token table when
+tied). It imports nothing of the serving program and makes its weights
+again from the seed, layer by layer, so it fits beside nothing the
+program holds. The layers are the architecture module's
+(``bench/architectures/<name>.py``); the pieces they are built from are
+here: :func:`_rmsnorm`, rotary positions (:func:`_rope`), blocked causal
+attention (:func:`causal_attention`) and matrix products that the control
+rounds (:func:`_mm`).
 
 A sequence is teacher-forced whole: prompt and served tokens together,
 padded at the end to a multiple of ``BUCKET`` so few shapes compile
@@ -28,10 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import layer_weights, seed_key, top_weights
-from bench.work import Widths
+from bench.weights import top_weights
 
-__all__ = ["reference_logits", "BUCKET"]
+__all__ = ["BUCKET", "causal_attention", "teacher_forced"]
 
 BUCKET = 512
 Q_BLOCK = 512
@@ -69,20 +71,12 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
-def _layer(x, p, w: Widths, theta: float, eps: float, control: bool):
-    """One decoder layer over a whole padded sequence x [S, D]."""
-    p = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), p)
-    S = x.shape[0]
-    H, KV, Dh = w.n_heads, w.n_kv_heads, w.head_dim
-    h = _rmsnorm(x, p["ln1"], eps)
-    q = _mm("sd,dhk->shk", h, p["attn"]["wq"], control, -1, 0)
-    k = _mm("sd,dhk->shk", h, p["attn"]["wk"], control, -1, 0)
-    v = _mm("sd,dhk->shk", h, p["attn"]["wv"], control, -1, 0)
-    q, k = _rope(q, theta), _rope(k, theta)
-    group = H // KV
-    k = jnp.repeat(k, group, axis=1)  # head h reads key/value head h // group
-    v = jnp.repeat(v, group, axis=1)
+def causal_attention(q, k, v, control: bool):
+    """Full causal attention of a whole padded sequence, in blocks of
+    ``Q_BLOCK`` query rows: q, k, v [S, H, Dh] (key/value heads already
+    repeated to the query heads), scores scaled by ``Dh ** -0.5``;
+    returns [S, H, Dh]."""
+    S, H, Dh = q.shape
     kpos = jnp.arange(S)
 
     def block(start):
@@ -93,12 +87,7 @@ def _layer(x, p, w: Widths, theta: float, eps: float, control: bool):
         pr = jax.nn.softmax(s, axis=-1)
         return _mm("hqs,shk->qhk", pr, v, control, -1, 0)
 
-    o = jax.lax.map(block, jnp.arange(0, S, Q_BLOCK)).reshape(S, H, Dh)
-    x = x + _mm("shk,hkd->sd", o, p["attn"]["wo"], control, (-2, -1), (0, 1))
-    h = _rmsnorm(x, p["ln2"], eps)
-    gate = _mm("sd,df->sf", h, p["mlp"]["wi_gate"], control, -1, 0)
-    up = _mm("sd,df->sf", h, p["mlp"]["wi_up"], control, -1, 0)
-    return x + _mm("sf,fd->sd", jax.nn.silu(gate) * up, p["mlp"]["wo"], control, -1, 0)
+    return jax.lax.map(block, jnp.arange(0, S, Q_BLOCK)).reshape(S, H, Dh)
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5))
@@ -110,19 +99,19 @@ def _head(x, final_norm, head, eps: float, tied: bool, control: bool):
     return _mm("sd,dv->sv", h, head, control, -1, 0)
 
 
-_layer_weights = jax.jit(layer_weights, static_argnums=(0,))
 _top_weights = jax.jit(top_weights, static_argnums=(0, 2))
 
 
-def reference_logits(w: Widths, seed: int, seqs, rows, *, theta: float, eps: float,
-                     tied: bool, control: bool = False):
+def teacher_forced(w, key, seqs, rows, layer, *, eps: float, tied: bool, control: bool):
     """Float32 logits of each sequence at the positions ``rows[i]``.
 
-    ``seqs[i]``: int token ids of the whole sequence; ``rows[i]``: the
-    positions whose next-token logits are wanted. Returns a list of
-    ``[len(rows[i]), vocab]`` float32 NumPy arrays.
+    ``w`` has ``n_layers``, ``d_model`` and ``vocab``; ``key`` is the
+    seed's (:func:`bench.weights.seed_key`). ``layer(l, xs)`` runs layer
+    ``l`` over every padded sequence ``xs`` ([S, D] float32 each) and
+    returns them. ``seqs[i]``: int token ids of the whole sequence;
+    ``rows[i]``: the positions whose next-token logits are wanted.
+    Returns a list of ``[len(rows[i]), vocab]`` float32 NumPy arrays.
     """
-    key = seed_key(seed)
     top = _top_weights(w, key, tied)
     tok = top["tok"]
     xs = []
@@ -133,10 +122,8 @@ def reference_logits(w: Widths, seed: int, seqs, rows, *, theta: float, eps: flo
         ids[:S] = s
         xs.append(tok[jnp.asarray(ids)].astype(jnp.float32))
     with jax.default_matmul_precision("highest"):
-        for layer in range(w.n_layers):
-            p = _layer_weights(w, key, layer)
-            xs = [_layer(x, p, w, theta, eps, control) for x in xs]
-            del p
+        for l in range(w.n_layers):
+            xs = layer(l, xs)
         head = tok if tied else top["lm_head"]
         out = []
         for x, r in zip(xs, rows):

@@ -2,18 +2,19 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Set-up makes the weights on the device from the seed, builds the
-``PipelineServer`` the cell's configuration describes, serves every
-distinct prompt length of the window's traffic once and then bursts of
-1, 2, ... requests, up to the lanes the cell's load file names on every
-replica at once, so that every program the window runs is compiled. The
-window then offers the cell's traffic open-loop for ``--seconds``; after
-it the server drains with no new arrivals for at most the cell's
-``drain_s``. Once the server is freed, a sample of the finished
-requests, drawn from the seed, is run through the float32 reference
-(:mod:`bench.reference`). ``correct`` says that no attempted request
-failed and that every sampled served token lies within the
-configuration's limit of the reference's best logit.
+Set-up makes the weights on the device from the seed and builds the
+``PipelineServer`` the cell's configuration describes, both through the
+configuration's architecture module (``bench/architectures/<name>.py``);
+it serves every distinct prompt length of the window's traffic once and
+then bursts of 1, 2, ... requests, up to the lanes the cell's load file
+names on every replica at once, so that every program the window runs is
+compiled. The window then offers the cell's traffic open-loop for
+``--seconds``; after it the server drains with no new arrivals for at
+most the cell's ``drain_s``. Once the server is freed, a sample of the
+finished requests, drawn from the seed, is run through the
+architecture's float32 reference (``reference_logits``). ``correct`` says
+that no attempted request failed and that every sampled served token
+lies within the configuration's limit of the reference's best logit.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
@@ -55,7 +56,7 @@ from bench.client_loop import PhaseClock, run_window  # noqa: E402
 from bench.peaks import NoChip, peaks_for  # noqa: E402
 from bench.spec import Cell, load_cell, load_metric  # noqa: E402
 from bench.traffic import generate, rng_for, warmup_lengths  # noqa: E402
-from bench.work import Widths, Work, token_work  # noqa: E402
+from bench.work import Work  # noqa: E402
 
 # The traced interval runs from this share of the window to its end:
 # stopping the profiler stalls the host while it writes the trace, so the
@@ -98,43 +99,13 @@ def device_info(chips: int, require_chip: bool) -> dict:
     return info
 
 
-def program_model(config: dict):
-    """The serving program's model, built to the configuration file."""
-    from repro.configs import get_config
-    from repro.models import build_model
-
-    p = config["program"]
-    cfg = dataclasses.replace(
-        get_config(p["arch"]),
-        n_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"],
-        vocab_size=config["vocab_size"],
-        rope_theta=float(config["rope_theta"]),
-        rms_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        dtype=config["torch_dtype"],
-        param_dtype=config["torch_dtype"],
-        attn_impl=p["attn_impl"],
-    )
-    return build_model(cfg)
-
-
 def build_server(cell: Cell, seed: int):
-    import jax.numpy as jnp
-
-    from bench.weights import served_params
     from repro.serving import PipelineServer
 
-    c, dep = cell.config, cell.config["deployment"]
-    model = program_model(c)
-    w = Widths.from_config(c)
+    c, dep, arch = cell.config, cell.config["deployment"], cell.arch
     return PipelineServer(
-        model,
-        served_params(w, seed, dep["n_groups"], bool(c["tie_word_embeddings"]),
-                      jnp.dtype(c["torch_dtype"])),
+        arch.program_model(c),
+        arch.served_params(arch.widths(c), seed, dep["n_groups"], c),
         n_groups=dep["n_groups"],
         n_replicas=dep["n_replicas"],
         policy=dep["policy"],
@@ -229,13 +200,14 @@ def end_to_end(clients, t0: float, end: float, seconds: float, setup_s: float) -
     return out
 
 
-def delivered_work(clients, lo: float, hi: float, w: Widths, chunk: int) -> Work:
-    """The work of the tokens that reached clients in ``[lo, hi]``."""
+def delivered_work(clients, lo: float, hi: float, arch, w, chunk: int) -> Work:
+    """The work of the tokens that reached clients in ``[lo, hi]``, as the
+    architecture module ``arch`` counts it at the widths ``w``."""
     total = Work()
     for c in clients:
         for k, t in enumerate(c.token_times):
             if lo <= t <= hi:
-                total = total + token_work(w, c.prompt_len, k, chunk)
+                total = total + arch.token_work(w, c.prompt_len, k, chunk)
     return total
 
 
@@ -300,22 +272,19 @@ def draw_sample(clients, seed: int, tokens: int):
     return [(np.asarray(c.req.prompt, np.int32), list(c.req.generated)) for c in sample]
 
 
-def logit_gaps(config: dict, seed: int, sample, control: bool = False):
+def logit_gaps(arch, config: dict, seed: int, sample, control: bool = False):
     """Per sampled stream, the gap at each served position between the
     reference's best logit and the reference's logit of the token served,
     or, with ``control``, of the token that the float8 control puts first
     at that position of the same prompt and served tokens: the control's
-    tokens put in the place of the program's."""
-    from bench.reference import reference_logits
-
-    w = Widths.from_config(config)
+    tokens put in the place of the program's. The reference is the
+    architecture module ``arch``'s."""
+    w = arch.widths(config)
     seqs = [np.concatenate([p, np.asarray(g[:-1], np.int32)]) for p, g in sample]
     rows = [np.arange(len(p) - 1, len(p) - 1 + len(g)) for p, g in sample]
-    kw = dict(theta=float(config["rope_theta"]), eps=float(config["rms_norm_eps"]),
-              tied=bool(config["tie_word_embeddings"]))
-    ref = reference_logits(w, seed, seqs, rows, **kw)
+    ref = arch.reference_logits(w, seed, seqs, rows, config)
     if control:
-        ctl = reference_logits(w, seed, seqs, rows, control=True, **kw)
+        ctl = arch.reference_logits(w, seed, seqs, rows, config, control=True)
         chosen = [c.argmax(axis=1) for c in ctl]
     else:
         chosen = [np.asarray(g) for _, g in sample]
@@ -372,7 +341,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     device = device_info(cell.chips, require_chip)
     log(f"device: {device}")
     c, dep = cell.config, cell.config["deployment"]
-    w = Widths.from_config(c)
+    w = cell.arch.widths(c)
     counter = CompileCounter()
     rate = float(cell.load["rate_per_s"])
     arrivals = generate(cell.mix, rate, seconds, seed, w.vocab)
@@ -455,7 +424,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             trace=tr, peaks=PEAKS.get(device["kind"]),
             steps=[s for s in clock.steps if len(s) == 4 and t0 <= s[0] < end],
             stats=delta, attempted=attempted,
-            work=delivered_work(clients, lo, hi, w, dep["prefill_chunk"]),
+            work=delivered_work(clients, lo, hi, cell.arch, w, dep["prefill_chunk"]),
         )
         result["metrics"] = {}
         for m in cell.per_layer:
@@ -476,14 +445,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     gc.collect()
     jax.clear_caches()
     t_ref = time.perf_counter()
-    gaps = logit_gaps(c, seed, sample) if sample else []
+    gaps = logit_gaps(cell.arch, c, seed, sample) if sample else []
     log(f"reference: {len(sample)} streams, {sum(len(g) for g in gaps)} served tokens, "
         f"{time.perf_counter() - t_ref:.1f} s")
     correct, checks = verdict(c, failed, gaps)
     if control:
         # The control in the program's place, judged by the same verdict.
         result["program"] = {"correct": correct, "checks": checks}
-        correct, checks = verdict(c, failed, logit_gaps(c, seed, sample, control=True)
+        correct, checks = verdict(c, failed, logit_gaps(cell.arch, c, seed, sample, control=True)
                                   if sample else [])
     result["correct"], result["checks"] = correct, checks
     return result

@@ -33,7 +33,6 @@ from bench.client_loop import run_window  # noqa: E402
 from bench.run import build_server, device_info, end_to_end, log, use_compile_cache, warm_up  # noqa: E402
 from bench.spec import load_cell  # noqa: E402
 from bench.traffic import generate, warmup_lengths  # noqa: E402
-from bench.work import Widths  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -46,7 +45,7 @@ def main(argv=None) -> int:
     cell = load_cell(args.workload)
     use_compile_cache()
     log(f"device: {device_info(cell.chips, True)}")
-    w = Widths.from_config(cell.config)
+    w = cell.arch.widths(cell.config)
     server = build_server(cell, args.seed)
     for rate in [float(r) for r in args.rates.split(",")]:
         arrivals = generate(cell.mix, rate, args.seconds, args.seed, w.vocab)

@@ -1,49 +1,22 @@
-"""Weights from a seed, in the layout the serving program takes.
+"""Weights from a seed: the pieces every architecture module shares.
 
 Each leaf of each layer is drawn from its own key, ``fold_in`` of the
 seed's key with the layer and the leaf, so one layer can be made again
-alone: the reference does that, layer by layer, and never reads what the
+alone: a reference does that, layer by layer, and never reads what the
 program holds. The program gets the weights of a pipeline stage as one
 stacked array per leaf, made on the device in one jitted call in the type
-they are served in.
-
-The layout is that of a uniform pre-norm decoder: RMSNorm, attention with
-``wq [D, H, Dh]``, ``wk``/``wv [D, KV, Dh]``, ``wo [H, Dh, D]``, RMSNorm,
-SwiGLU ``wi_gate``/``wi_up [D, F]``, ``wo [F, D]``; the token table
-``[V, D]``, a final RMSNorm and, when not tied, ``lm_head [D, V]``.
+they are served in, and handed over stage by stage (:class:`StageStacks`).
+The layer's leaves are the architecture module's
+(``bench/architectures/<name>.py``); the token table ``[V, D]``, the
+final RMSNorm and, when not tied, ``lm_head [D, V]`` are here.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
-from bench.work import Widths
-
-__all__ = ["LAYER_LEAVES", "layer_weights", "top_weights", "stage_ranges", "served_params"]
-
-# A layer's leaves as (group, leaf); the group "" is the layer itself.
-LAYER_LEAVES = (
-    ("", "ln1"), ("attn", "wq"), ("attn", "wk"), ("attn", "wv"), ("attn", "wo"),
-    ("", "ln2"), ("mlp", "wi_gate"), ("mlp", "wi_up"), ("mlp", "wo"),
-)
-
-
-def _leaf_shape_std(w: Widths, group: str, leaf: str):
-    D, H, KV, Dh, F = w.d_model, w.n_heads, w.n_kv_heads, w.head_dim, w.d_ff
-    return {
-        ("", "ln1"): ((D,), None),
-        ("", "ln2"): ((D,), None),
-        ("attn", "wq"): ((D, H, Dh), D**-0.5),
-        ("attn", "wk"): ((D, KV, Dh), D**-0.5),
-        ("attn", "wv"): ((D, KV, Dh), D**-0.5),
-        ("attn", "wo"): ((H, Dh, D), (H * Dh) ** -0.5),
-        ("mlp", "wi_gate"): ((D, F), D**-0.5),
-        ("mlp", "wi_up"): ((D, F), D**-0.5),
-        ("mlp", "wo"): ((F, D), F**-0.5),
-    }[(group, leaf)]
+__all__ = ["seed_key", "top_weights", "stage_ranges", "StageStacks"]
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -59,21 +32,7 @@ def _draw(key, shape, std, dtype):
     return x.astype(dtype)
 
 
-def layer_weights(w: Widths, key, layer, dtype=jnp.bfloat16) -> dict:
-    """One layer's leaves as ``{"ln1", "attn": {...}, "ln2", "mlp": {...}}``."""
-    k_layer = jax.random.fold_in(key, layer)
-    out: dict = {"attn": {}, "mlp": {}}
-    for i, (group, leaf) in enumerate(LAYER_LEAVES):
-        shape, std = _leaf_shape_std(w, group, leaf)
-        x = _draw(jax.random.fold_in(k_layer, i), shape, std, dtype)
-        if group:
-            out[group][leaf] = x
-        else:
-            out[leaf] = x
-    return out
-
-
-def top_weights(w: Widths, key, tied: bool, dtype=jnp.bfloat16) -> dict:
+def top_weights(w, key, tied: bool, dtype=jnp.bfloat16) -> dict:
     """Token table, final norm gain and (untied) output head."""
     k_top = jax.random.fold_in(key, 1 << 20)
     D, V = w.d_model, w.vocab
@@ -114,32 +73,3 @@ class StageStacks:
         if key not in self._by_range:
             raise IndexError(f"only whole stages can be taken: {idx!r}")
         return self._by_range.pop(key)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 2, 3, 4))
-def _make_all(w: Widths, key, ranges: tuple, tied: bool, dtype):
-    stages = []
-    for lo, hi in ranges:
-        layers = [layer_weights(w, key, l, dtype) for l in range(lo, hi)]
-        stages.append(jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *layers))
-    return tuple(stages), top_weights(w, key, tied, dtype)
-
-
-def served_params(w: Widths, seed: int, n_stages: int, tied: bool, dtype=jnp.bfloat16):
-    """The full model's weight tree for the server, made on the device in
-    one jitted call. The class leaves are :class:`StageStacks`."""
-    ranges = tuple(stage_ranges(w.n_layers, n_stages))
-    stages, top = _make_all(w, seed_key(seed), ranges, tied, dtype)
-    c0: dict = {"attn": {}, "mlp": {}}
-    for group, leaf in LAYER_LEAVES:
-        stacks = StageStacks({
-            r: (s[group][leaf] if group else s[leaf]) for r, s in zip(ranges, stages)
-        })
-        if group:
-            c0[group][leaf] = stacks
-        else:
-            c0[leaf] = stacks
-    embed = {"tok": top["tok"]}
-    if not tied:
-        embed["lm_head"] = top["lm_head"]
-    return {"classes": {"c0": c0}, "embed": embed, "final_norm": top["final_norm"]}

@@ -19,7 +19,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from bench import run  # noqa: E402
-from bench.spec import Cell  # noqa: E402
+from bench.spec import Cell, load_architecture  # noqa: E402
 from bench.traffic import Mix  # noqa: E402
 
 SEED = 2**33 + 12345
@@ -48,7 +48,8 @@ def tiny_cell(config="stablelm-1.6b.g3r2"):
     })
     e2e = [{"name": n, "unit": u} for n, u in
            [("ttft_p50_s", "s"), ("itl_p50_ms", "ms"), ("tokens_per_s", "tokens/s"), ("setup_s", "s")]]
-    return Cell("tiny", 1, cfg, mix, {"rate_per_s": 4.0, "drain_s": 60}, e2e, [])
+    return Cell("tiny", 1, cfg, mix, {"rate_per_s": 4.0, "drain_s": 60}, e2e, [],
+                load_architecture(cfg))
 
 
 def run_tiny(config="stablelm-1.6b.g3r2"):
@@ -162,6 +163,7 @@ def test_float8_control_reads_above_the_limit(seed):
                num_attention_heads=8, num_key_value_heads=8, vocab_size=2048)
     rng = np.random.default_rng(seed)
     sample = [(rng.integers(0, 2048, 40).astype(np.int32), [0] * 120) for _ in range(4)]
-    correct, checks = run.verdict(cfg, 0, run.logit_gaps(cfg, SEED + seed, sample, control=True))
+    gaps = run.logit_gaps(load_architecture(cfg), cfg, SEED + seed, sample, control=True)
+    correct, checks = run.verdict(cfg, 0, gaps)
     assert correct is False
     assert checks["max_logit_gap"]["value"] > cfg["correct"]["max_logit_gap"]

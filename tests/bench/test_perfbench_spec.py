@@ -13,7 +13,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from bench.peaks import NoChip, peaks_for  # noqa: E402
-from bench.spec import load_cell, load_metric  # noqa: E402
+from bench.spec import load_architecture, load_cell, load_metric  # noqa: E402
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in SPEC["workloads"]]
@@ -38,16 +38,10 @@ def test_every_metric_has_a_reader_that_says_what_it_is(metric):
 
 @pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda e: e["name"])
 def test_configuration_is_the_programs_model_at_published_widths(entry):
-    from repro.configs import get_config
-
+    """The configuration's architecture module ties the file to the
+    program's own configuration (``check_program`` raises where they part)."""
     c = json.loads((ROOT / entry["file"]).read_text())
-    p = get_config(c["program"]["arch"])
-    assert (p.n_layers, p.d_model, p.n_heads, p.n_kv_heads, p.d_ff, p.vocab_size) == (
-        c["num_hidden_layers"], c["hidden_size"], c["num_attention_heads"],
-        c["num_key_value_heads"], c["intermediate_size"], c["vocab_size"])
-    assert p.tie_embeddings == c["tie_word_embeddings"]
-    assert p.rope_theta == c["rope_theta"] and p.rms_eps == c["rms_norm_eps"]
-    assert p.act == "swiglu" and p.block == "attn" and p.attn_window is None
+    load_architecture(c).check_program(c)
 
 
 def test_peaks_refuse_other_devices():

@@ -9,11 +9,15 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench.work import Widths, request_work, token_work  # noqa: E402
+from bench.spec import load_architecture  # noqa: E402
+
+DENSE = load_architecture({"architecture": "dense"})
+token_work, request_work = DENSE.token_work, DENSE.request_work
 
 
 def widths(name):
-    return Widths.from_config(json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text()))
+    c = json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text())
+    return load_architecture(c).widths(c)
 
 
 STABLELM = "stablelm-1.6b.g3r2"
